@@ -111,7 +111,8 @@ def capture_moe() -> TransferTrace:
     # a 1-device model axis: the shard_map/descriptor path is identical to
     # the multi-device one (same a2a/reduce tasks, same shapes per shard),
     # so the capture needs no device fleet — replay supplies the fabric.
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     cfg = cfg.with_axes(Axes(batch=(), model="model", model_size=1,
                              batch_size=1))
     sched = DistributedScheduler(Topology.parallel(2, prefix="a2a"),
@@ -139,7 +140,8 @@ def capture_train() -> TransferTrace:
     shape = ShapeConfig("t", 16, 4, "train", microbatches=1)
     ds = SyntheticLM(vocab=cfg.vocab, seq_len=16, global_batch=4, seed=0)
     state = init_state(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((1,), ("dp",))
+    mesh = jax.make_mesh((1,), ("dp",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     step = make_dp_train_step(cfg, shape, mesh=mesh, axis="dp",
                               compressed=True)
     from repro.runtime import telemetry

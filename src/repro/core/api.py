@@ -173,7 +173,7 @@ def _resolve_auto(desc: XDMADescriptor, x, link=None) -> XDMADescriptor:
     return _autotune.resolve_descriptor(desc, shape, leaf.dtype, link=link)
 
 
-def _compiled_or(desc: XDMADescriptor, interpret: bool,
+def _compiled_or(desc: XDMADescriptor,
                  compiled: Optional[Callable]) -> Callable:
     """Compiled fused kernel with a structural escape hatch: payload pytrees
     (QTensor/CTensor inputs) re-enter through the XLA composition, which
@@ -186,26 +186,26 @@ def _compiled_or(desc: XDMADescriptor, interpret: bool,
     return jax.jit(run)
 
 
-def _lower(desc: XDMADescriptor, interpret: bool) -> Callable:
-    """Build the Data-phase callable for a descriptor (the CFG phase)."""
+def _lower(desc: XDMADescriptor) -> Callable:
+    """Build the Data-phase callable for a descriptor (the CFG phase).
+    Kernels compile for a TPU and interpret on the CPU backend
+    (:func:`repro.kernels.interpret_mode`)."""
     movement = desc.movement
     if movement == "local":
         if desc.backend == "pallas":
             def run(x):
-                return engine.xdma_copy_pallas(x, desc, interpret=interpret)
+                return engine.xdma_copy_pallas(x, desc)
             return run
         if desc.backend == "compiled":
             # forced single-kernel lowering: raises on non-fusible chains
-            return jax.jit(plugin_compiler.compile_local(desc,
-                                                         interpret=interpret))
+            return jax.jit(plugin_compiler.compile_local(desc))
         if desc.backend == "auto":
             # plugin-compiler policy: fuse emit-capable plugin chains into
             # one Pallas kernel; everything else keeps the XLA composition
             # (see plugin_compiler.cfg_stats() for the fused/fallback tally)
-            compiled = plugin_compiler.maybe_compile_local(desc,
-                                                           interpret=interpret)
+            compiled = plugin_compiler.maybe_compile_local(desc)
             if compiled is not None:
-                return _compiled_or(desc, interpret, compiled)
+                return _compiled_or(desc, compiled)
         # fused path: jit here so repeated transfers share one executable
         return jax.jit(lambda x: engine.xdma_copy(x, desc))
 
@@ -226,11 +226,9 @@ def _lower(desc: XDMADescriptor, interpret: bool) -> Callable:
     src_side = dst_side = None
     if movement in ("peer", "all_to_all", "multicast"):
         src_side = plugin_compiler.maybe_compile_side(
-            desc.src.layout, desc.pre, side="src", d_buf=desc.d_buf,
-            interpret=interpret)
+            desc.src.layout, desc.pre, side="src", d_buf=desc.d_buf)
         dst_side = plugin_compiler.maybe_compile_side(
-            desc.dst.layout, desc.post, side="dst", d_buf=desc.d_buf,
-            interpret=interpret)
+            desc.dst.layout, desc.post, side="dst", d_buf=desc.d_buf)
 
     def run_remote(x):
         fuse_src = (src_side is not None
@@ -294,30 +292,28 @@ def _lower(desc: XDMADescriptor, interpret: bool) -> Callable:
     return run_remote
 
 
-def _lowered(desc: XDMADescriptor, interpret: bool) -> Callable:
-    key = (desc.cache_key(), bool(interpret))
+def _lowered(desc: XDMADescriptor) -> Callable:
+    key = desc.cache_key()
     entry = _CACHE.get(key)
     if entry is not None:
         _BANK.inc("hits")
         _CACHE.move_to_end(key)
         return entry[1]
     _BANK.inc("misses")
-    fn = _lower(desc, interpret)
+    fn = _lower(desc)
     _CACHE[key] = (desc, fn)
     _evict_to_capacity()
     return fn
 
 
-def transfer(x: jnp.ndarray, desc: XDMADescriptor, *,
-             interpret: bool = True) -> Any:
+def transfer(x: jnp.ndarray, desc: XDMADescriptor) -> Any:
     """Execute one XDMA task described entirely by ``desc``.
 
     ``x`` is the physical buffer at the src endpoint; the return value is the
     physical buffer at the dst endpoint (a :class:`~repro.core.plugins.QTensor`
     when the surviving chain ends in ``Quantize``).  Remote movements must be
     called inside ``shard_map`` (or jit with sharded inputs), exactly like
-    the backend functions they lower to.  ``interpret`` only affects the
-    Pallas backend (kernels run in interpret mode off-TPU).
+    the backend functions they lower to.
 
     When a :func:`repro.runtime.trace.capture` scope is open, every call is
     recorded into the ambient :class:`~repro.runtime.trace.TransferTrace`;
@@ -328,11 +324,11 @@ def transfer(x: jnp.ndarray, desc: XDMADescriptor, *,
     desc = _resolve_auto(desc, x)
     tel = _tm._ACTIVE
     if tel is None:
-        out = _lowered(desc, interpret)(x)
+        out = _lowered(desc)(x)
     else:
         with tel.span("xdma.transfer", track="transfer",
                       desc=desc.summary(), movement=desc.movement):
-            out = _lowered(desc, interpret)(x)
+            out = _lowered(desc)(x)
     if _CAPTURE is not None:
         _CAPTURE.record_transfer(x, desc, out)
     return out
@@ -354,8 +350,8 @@ class XDMAQueue:
                  name: str = "queue"):
         self.name = name
         self._descs: List[XDMADescriptor] = []
-        self._fused: Dict[bool, Callable] = {}          # keyed by interpret
-        self._tasks: Dict[Tuple[int, bool], Callable] = {}
+        self._fused: Optional[Callable] = None
+        self._tasks: Dict[Tuple, Callable] = {}
         for d in descriptors:
             self.submit(d)
 
@@ -364,7 +360,7 @@ class XDMAQueue:
         if not isinstance(desc, XDMADescriptor):
             raise TypeError(f"XDMAQueue.submit takes a descriptor, got {type(desc)}")
         self._descs.append(desc)
-        self._fused.clear()             # new CFG phase needed for the chain
+        self._fused = None              # new CFG phase needed for the chain
         return len(self._descs) - 1
 
     @property
@@ -395,7 +391,7 @@ class XDMAQueue:
         return dtype
 
     # -- execution ----------------------------------------------------------
-    def _task(self, i: int, interpret: bool,
+    def _task(self, i: int,
               desc: Optional[XDMADescriptor] = None) -> Callable:
         # Queue-local memo (not the global CFG cache): queues are routinely
         # rebuilt per trace inside shard_map bodies, and id-keyed global
@@ -405,34 +401,33 @@ class XDMAQueue:
         base = self._descs[i]
         if desc is None:
             desc = base
-        key = ((i, interpret) if desc is base
-               else (i, interpret, desc.cache_key()))
+        key = (i,) if desc is base else (i, desc.cache_key())
         fn = self._tasks.get(key)
         if fn is None:
-            fn = _lower(desc, interpret)
+            fn = _lower(desc)
             self._tasks[key] = fn
         return fn
 
-    def run_task(self, x, i: int, *, interpret: bool = True):
+    def run_task(self, x, i: int):
         """Dispatch task ``i`` alone (in-order use is the caller's contract)."""
         desc = _resolve_auto(self._descs[i], x)
         tel = _tm._ACTIVE
         if tel is None:
-            out = self._task(i, interpret, desc)(x)
+            out = self._task(i, desc)(x)
         else:
             with tel.span("XDMAQueue.run_task", track="queue",
                           queue=self.name, task=i):
-                out = self._task(i, interpret, desc)(x)
+                out = self._task(i, desc)(x)
         if _CAPTURE is not None:
             _CAPTURE.record_transfer(x, desc, out, source="queue",
                                      label=f"{self.name}[{i}]")
         return out
 
-    def run(self, x, *, interpret: bool = True):
+    def run(self, x):
         """Dispatch the whole queue in order as one fused program."""
         if not self._descs:
             return x
-        fused = self._fused.get(interpret)
+        fused = self._fused
         if fused is None:
             descs = tuple(self._descs)
 
@@ -442,11 +437,11 @@ class XDMAQueue:
                     if d.movement == "local" and d.backend != "pallas":
                         v = engine.xdma_copy(v, d)     # fuse into the chain
                     else:
-                        v = self._task(i, interpret, d)(v)
+                        v = self._task(i, d)(v)
                 return v
 
             fused = jax.jit(chain) if self.is_local else chain
-            self._fused[interpret] = fused
+            self._fused = fused
         tel = _tm._ACTIVE
         if tel is None:
             out = fused(x)

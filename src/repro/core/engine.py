@@ -16,8 +16,9 @@ Two lowerings of the same descriptor:
   selection is by *pattern*, not by layout special cases: any 2D relayout /
   transpose the planner can express lowers through the one kernel, the rest
   (plugin chains, rank > 2, incompatible nests) falls back to the fused path
-  — ``repro.kernels.agu.agu_stats()`` records why.  On this CPU container
-  the kernel runs in interpret mode.
+  — ``repro.kernels.agu.agu_stats()`` records why.  The kernel compiles
+  for a TPU and interprets on the CPU backend
+  (``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -76,8 +77,7 @@ def xdma_copy_jit(x: jnp.ndarray, desc: XDMADescriptor) -> jnp.ndarray:
     return xdma_copy(x, desc)
 
 
-def xdma_copy_pallas(x: jnp.ndarray, desc: XDMADescriptor, *,
-                     interpret: bool = True) -> jnp.ndarray:
+def xdma_copy_pallas(x: jnp.ndarray, desc: XDMADescriptor) -> jnp.ndarray:
     """TPU-native lowering through the generic AGU kernel.
 
     Supports pure relayout and relayout+transpose on 2D logical data (the
@@ -98,5 +98,4 @@ def xdma_copy_pallas(x: jnp.ndarray, desc: XDMADescriptor, *,
         dst_layout=desc.dst_layout,
         transpose=pure_transpose,
         d_buf=desc.d_buf,
-        interpret=interpret,
     )

@@ -23,7 +23,9 @@ Two kernel templates:
   stages.
 
 Any chain containing a plugin without ``emit`` (e.g. ``Quantize``, whose
-QTensor payload splits the stream) falls back to the fused-XLA composition —
+QTensor payload splits the stream), or whose output is still a payload
+pytree (``Compress`` not followed by ``Decompress``: Mosaic cannot write the
+rank-1 bool mask), falls back to the fused-XLA composition —
 behaviour is identical by construction and enforced bitwise by the
 differential harness (``tests/test_differential.py``).  :func:`cfg_stats`
 reports how many CFG phases fused vs fell back, and why.
@@ -79,10 +81,22 @@ def _record(fused: bool, reason: str = "") -> None:
 
 # -- fusibility --------------------------------------------------------------
 def _chain_fusible(chain: Sequence[P.Plugin]) -> Optional[str]:
-    """None when every plugin has an emit hook, else the fallback reason."""
+    """None when the chain lowers to one Mosaic kernel, else the fallback
+    reason.  Two rules: every plugin needs an emit hook, and a payload
+    pytree (a ``CTensor``'s mask) may live inside the kernel but not leave
+    it — Mosaic has no rank-1 or bool kernel outputs, and the compiler
+    aborts the process on them, so such chains are refused here, never
+    tried."""
+    open_payload = None
     for p in chain:
         if not p.supports_emit:
             return f"no-emit:{p.name}"
+        if p.pytree_payload:
+            open_payload = p
+        elif p.unpacks_payload:
+            open_payload = None
+    if open_payload is not None:
+        return f"payload-output:{open_payload.name}"
     return None
 
 
@@ -143,51 +157,26 @@ def _out_struct(in_aval, src_layout, chain):
     return jax.eval_shape(f, in_aval)
 
 
-def _physical_struct(struct, dst_layout):
-    """Physicalize the chain output: the primary payload leaf gets the dst
-    layout; side-channels (a CTensor mask) are written raw, exactly as the
-    XLA composition does."""
-    if isinstance(struct, P.CTensor):
-        v = struct.values
-        return [jax.ShapeDtypeStruct(dst_layout.physical_shape(v.shape), v.dtype),
-                jax.ShapeDtypeStruct(struct.mask.shape, struct.mask.dtype)]
-    return [jax.ShapeDtypeStruct(dst_layout.physical_shape(struct.shape),
-                                 struct.dtype)]
-
-
-def _pack_out(v, dst_layout):
-    """Chain output pytree -> ordered list of physical output blocks."""
-    if isinstance(v, P.CTensor):
-        return [_write_stage(v.values, dst_layout), v.mask]
-    return [_write_stage(v, dst_layout)]
-
-
-def _unpack_out(outs, struct):
-    return P.CTensor(*outs) if isinstance(struct, P.CTensor) else outs[0]
-
-
 def _compile_block(chain, src_layout, dst_layout, in_aval, interpret):
-    """Whole-array template: one grid step, full blocks through VMEM."""
+    """Whole-array template: one grid step, full blocks through VMEM.  The
+    chain's output is a plain array (:func:`_chain_fusible` keeps payload
+    pytrees inside the kernel)."""
+    from repro.kernels import interpret_mode
     counts, consts = _chain_consts(chain)
     struct = _out_struct(in_aval, src_layout, chain)
-    out_shape = _physical_struct(struct, dst_layout)
 
     def kernel(x_ref, *refs):
-        const_refs, out_refs = refs[:len(consts)], refs[len(consts):]
+        const_refs, out_ref = refs[:len(consts)], refs[len(consts)]
         v = _read_stage(x_ref[...], src_layout)
         v = _emit_chain(v, chain, counts, tuple(r[...] for r in const_refs))
-        for ref, blk in zip(out_refs, _pack_out(v, dst_layout)):
-            ref[...] = blk
+        out_ref[...] = _write_stage(v, dst_layout)
 
-    call = pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret)
-
-    def run(x):
-        outs = call(x, *consts)
-        if not isinstance(outs, (list, tuple)):
-            outs = [outs]
-        return _unpack_out(list(outs), struct)
-
-    return run
+    call = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(
+            dst_layout.physical_shape(struct.shape), struct.dtype),
+        interpret=interpret_mode(interpret))
+    return lambda x: call(x, *consts)
 
 
 def _burst_rows(chain, src_layout, dst_layout, m: int, d_buf: int) -> Optional[int]:
@@ -208,6 +197,7 @@ def _burst_rows(chain, src_layout, dst_layout, m: int, d_buf: int) -> Optional[i
 
 def _compile_streamed(chain, src_layout, dst_layout, in_aval, d_buf, interpret):
     """Row-burst template for all-streaming chains (d_buf-deep bursts)."""
+    from repro.kernels import interpret_mode
     from repro.kernels.agu import slab_spec
     logical = src_layout.logical_shape(in_aval.shape)
     if len(logical) != 2:
@@ -240,18 +230,77 @@ def _compile_streamed(chain, src_layout, dst_layout, in_aval, d_buf, interpret):
         out_specs=spec(dst_layout, n),
         out_shape=jax.ShapeDtypeStruct(dst_layout.physical_shape((m, n)),
                                        out_dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )
     return lambda x: call(x, *consts)
 
 
+# The whole-block template holds its input, its output and an f32 copy of
+# the logical block in VMEM at once.  A v5e kernel gets 16 MiB of scoped
+# VMEM by default; blocks up to half of it leave the rest to the kernel's
+# own temporaries.  Larger arrays take the XLA composition.
+_BLOCK_VMEM_BYTES = 8 << 20
+
+
+def _unit_lead(chain, src_layout, dst_layout, logical, out_logical) -> int:
+    """Leading logical dims of extent 1 that both layouts keep as plain
+    leading physical dims: dropping them leaves every byte in place, so a
+    (1, S, d) movement runs the (S, d) kernel.  Plugins that address an
+    absolute axis keep the full rank."""
+    if any(getattr(p, "axis", -1) >= 0 for p in chain):
+        return 0
+    k = 0
+    while k < len(logical) - 2 and logical[k] == 1:
+        k += 1
+    plain = [(d, "plain") for d in range(k)]
+    while k and (src_layout._phys_dims(len(logical))[:k] != plain[:k]
+                 or dst_layout._phys_dims(len(out_logical))[:k] != plain[:k]):
+        k -= 1
+    return k
+
+
+def _composition(chain, src_layout, dst_layout):
+    """The XLA composition of the same datapath (bit-identical by
+    construction: the same reader, plugin and writer ops)."""
+    return lambda x: _write_stage(
+        P.apply_chain(chain, _read_stage(x, src_layout)), dst_layout)
+
+
 def _compile_for_aval(chain, src_layout, dst_layout, d_buf, in_aval, interpret):
-    streaming = all(p.streaming for p in chain)
-    if streaming and len(in_aval.shape) >= 2:
+    """Pick the kernel for one input shape.  In order: squeeze unit leading
+    dims; a lone ``Transpose`` is the AGU's transposing relayout (gridded
+    superblocks, so any size fits VMEM); all-streaming chains take the
+    row-burst template; the rest take the whole-block template when it fits
+    VMEM, else the XLA composition (tallied as ``vmem-block``)."""
+    from repro.kernels import agu
+    logical = src_layout.logical_shape(in_aval.shape)
+    out_logical = P.chain_out_shape(chain, logical)
+    k = _unit_lead(chain, src_layout, dst_layout, logical, out_logical)
+    if k:
+        inner_shape = src_layout.physical_shape(logical[k:])
+        inner = _compile_for_aval(
+            chain, src_layout, dst_layout, d_buf,
+            jax.ShapeDtypeStruct(inner_shape, in_aval.dtype), interpret)
+        out_shape = dst_layout.physical_shape(out_logical)
+        return lambda x: inner(x.reshape(inner_shape)).reshape(out_shape)
+    if (len(chain) == 1 and isinstance(chain[0], P.Transpose)
+            and len(logical) == 2):
+        plan, _ = agu.plan_relayout(src_layout, dst_layout, logical,
+                                    transpose=True, d_buf=d_buf)
+        if plan is not None:
+            return lambda x: plan.run(x, interpret=interpret)
+    if all(p.streaming for p in chain):
         fn = _compile_streamed(chain, src_layout, dst_layout, in_aval,
                                d_buf, interpret)
         if fn is not None:
             return fn
+    out_dtype = P.chain_out_dtype(chain, in_aval.dtype)
+    block = (math.prod(in_aval.shape) * jnp.dtype(in_aval.dtype).itemsize
+             + math.prod(out_logical) * jnp.dtype(out_dtype).itemsize
+             + math.prod(logical) * 4)
+    if block > _BLOCK_VMEM_BYTES:
+        _record(False, "vmem-block")
+        return _composition(chain, src_layout, dst_layout)
     return _compile_block(chain, src_layout, dst_layout, in_aval, interpret)
 
 
@@ -276,7 +325,8 @@ def _specializing(chain, src_layout, dst_layout, d_buf, interpret, validate):
 
 
 # -- public entry points -----------------------------------------------------
-def compile_local(desc: XDMADescriptor, *, interpret: bool = True) -> Callable:
+def compile_local(desc: XDMADescriptor, *,
+                  interpret: Optional[bool] = None) -> Callable:
     """The full local datapath as one kernel; raises when not fusible.
 
     The returned callable specializes (and memoizes) one ``pallas_call`` per
@@ -298,19 +348,19 @@ def compile_local(desc: XDMADescriptor, *, interpret: bool = True) -> Callable:
                          interpret, validate)
 
 
-def maybe_compile_local(desc: XDMADescriptor, *,
-                        interpret: bool = True) -> Optional[Callable]:
+def maybe_compile_local(desc: XDMADescriptor) -> Optional[Callable]:
     """``backend='auto'`` policy + stats: the compiled datapath, or None to
     signal the XLA-composition fallback."""
     ok, reason = can_fuse(desc)
     _record(ok, reason)
     if not ok:
         return None
-    return compile_local(desc, interpret=interpret)
+    return compile_local(desc)
 
 
 def compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *, side: str,
-                 d_buf: int = 9, interpret: bool = True) -> Callable:
+                 d_buf: int = 9,
+                 interpret: Optional[bool] = None) -> Callable:
     """One endpoint side of a remote movement as a single kernel.
 
     ``side='src'``: reader + pre-chain (physical src buffer -> link payload);
@@ -332,8 +382,7 @@ def compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *, side: str,
 
 
 def maybe_compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *,
-                       side: str, d_buf: int = 9,
-                       interpret: bool = True) -> Optional[Callable]:
+                       side: str, d_buf: int = 9) -> Optional[Callable]:
     """Side-fusion policy for remote movements: fuse a non-empty, fully
     emit-capable chain whose payload stays a plain array (pytree payloads
     like QTensor/CTensor split the stream across the collective), else None.
@@ -351,5 +400,4 @@ def maybe_compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *,
     _record(reason is None, reason or "")
     if reason is not None:
         return None
-    return compile_side(layout, chain, side=side, d_buf=d_buf,
-                        interpret=interpret)
+    return compile_side(layout, chain, side=side, d_buf=d_buf)

@@ -86,6 +86,10 @@ class Plugin:
       chain as a *remote* endpoint side, because the collective between the
       sides only carries the payload types the remote backends know how to
       split.
+    * ``unpacks_payload`` — a plugin that takes such a payload pytree and
+      returns a plain array (``Decompress``, ``Dequantize``).  A fused
+      kernel may carry a payload pytree between stages, but its output must
+      be a plain array, so the compiler needs to see where one is unpacked.
     """
 
     name: str = "plugin"
@@ -93,6 +97,7 @@ class Plugin:
     streaming: bool = False
     changes_rank: bool = False
     pytree_payload: bool = False
+    unpacks_payload: bool = False
 
     def __call__(self, x: Any) -> Any:  # pragma: no cover - interface
         raise NotImplementedError
@@ -274,6 +279,7 @@ class Quantize(Plugin):
 class Dequantize(Plugin):
     dtype: Any = jnp.float32
     name: str = "dequantize_int8"
+    unpacks_payload = True
 
     def __call__(self, x: QTensor):
         return (x.values.astype(jnp.float32) * x.scales).astype(self.dtype)
@@ -394,7 +400,20 @@ class Compress(Plugin):
         mask = jnp.any(blocks != 0, axis=(-1, -2))
         return CTensor(values=x, mask=mask)
 
-    emit = __call__
+    def emit(self, x) -> CTensor:
+        # Kernel form: Mosaic lowers neither rank-1 vectors nor bool arrays,
+        # so in VMEM the mask is a float keep factor per logical row,
+        # (..., M, 1), constant over each block.  It never leaves the
+        # kernel: a chain whose output is still compressed does not fuse
+        # (plugin_compiler), and Decompress.emit consumes this form.
+        m, n = x.shape[-2:]
+        br = self.block_rows
+        nz = (x.astype(jnp.float32) != 0).astype(jnp.float32)
+        blocks = nz.reshape(x.shape[:-2] + (m // br, br, n))
+        occ = jnp.max(jnp.max(blocks, axis=-1, keepdims=True), axis=-2,
+                      keepdims=True)
+        keep = jnp.broadcast_to(occ, occ.shape[:-2] + (br, 1))
+        return CTensor(values=x, mask=keep.reshape(x.shape[:-2] + (m, 1)))
 
 
 @register_plugin
@@ -408,6 +427,7 @@ class Decompress(Plugin):
     """
 
     name: str = "decompress_blocksparse"
+    unpacks_payload = True
 
     def __call__(self, x: CTensor):
         v, mask = x.values, x.mask
@@ -416,7 +436,11 @@ class Decompress(Plugin):
         keep = jnp.repeat(mask, block_rows, axis=-1).astype(v.dtype)
         return v * keep[..., :, None]
 
-    emit = __call__
+    def emit(self, x: CTensor):
+        # consumes Compress.emit's per-row keep factor; multiplying by an
+        # exact 0/1 in f32 is bit-equal to the composition's native product
+        v = x.values
+        return (v.astype(jnp.float32) * x.mask).astype(v.dtype)
 
 
 @register_plugin

@@ -1,2 +1,32 @@
-"""Pallas TPU kernels for XDMA hot paths (validated on CPU via interpret=True)."""
-from . import ops, ref  # noqa: F401
+"""Pallas TPU kernels for XDMA hot paths.
+
+Every ``pallas_call`` in the repo takes its ``interpret`` flag from
+:func:`interpret_mode`, so there is one rule for the whole library: compiled
+Mosaic kernels on a TPU, the Pallas interpreter on the CPU backend (how the
+test suite runs), and an error on any other platform.
+"""
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Resolve a kernel's ``interpret`` flag.
+
+    ``None`` (every library caller) decides from the backend the kernel is
+    traced for: ``False`` on ``tpu``, ``True`` on ``cpu``.  An explicit bool
+    is honoured — compile tests force ``False`` to lower for a described TPU
+    from a CPU process.  Any other platform raises rather than silently
+    picking a mode."""
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas lowering for platform {platform!r}: "
+                       "kernels compile for 'tpu' and interpret on 'cpu'")
+
+
+from . import ops, ref  # noqa: E402,F401  (submodules import interpret_mode)

@@ -38,6 +38,8 @@ from jax.experimental import pallas as pl
 from repro.core import layouts as L
 from repro.runtime import telemetry as _tm
 
+from . import interpret_mode
+
 __all__ = ["plan_relayout", "AGUPlan", "agu_relayout", "agu_stats",
            "clear_agu_stats", "record_fallback", "record_plan", "eff_d_buf",
            "slab_spec"]
@@ -145,7 +147,8 @@ class AGUPlan:
         m, n = self.logical_shape
         return (n, m) if self.transpose else (m, n)
 
-    def run(self, x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+    def run(self, x: jnp.ndarray, *,
+            interpret: Optional[bool] = None) -> jnp.ndarray:
         if self.kind == "identity":
             return x
         m, n = self.logical_shape
@@ -172,7 +175,7 @@ class AGUPlan:
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(
                 self.dst_layout.physical_shape(self.out_logical), x.dtype),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(x)
 
 
@@ -249,7 +252,7 @@ def plan_relayout(src_layout: L.Layout, dst_layout: L.Layout,
 
 def agu_relayout(x: jnp.ndarray, *, src_layout: L.Layout,
                  dst_layout: L.Layout, transpose: bool = False,
-                 d_buf: int = 9, interpret: bool = True) -> jnp.ndarray:
+                 d_buf: int = 9) -> jnp.ndarray:
     """Force the generic AGU kernel; raises when the pair has no plan."""
     logical = src_layout.logical_shape(x.shape)
     plan, reason = plan_relayout(src_layout, dst_layout, logical,
@@ -259,4 +262,4 @@ def agu_relayout(x: jnp.ndarray, *, src_layout: L.Layout,
             f"no AGU kernel plan for {src_layout.name}->{dst_layout.name}"
             f"{' transposed' if transpose else ''} on {logical} ({reason})")
     record_plan(plan)
-    return plan.run(x, interpret=interpret)
+    return plan.run(x)

@@ -8,7 +8,8 @@ output (the XDMA Frontend discipline applied to attention).
 
 Grid: (BH, nq, nk) with nk innermost (sequential); scratch persists per
 (BH, qi) program family.  Causal/window masking via an additive bias
-computed from program ids.  Validated in interpret mode against ref.py.
+computed from program ids.  Checked against ref.py in interpret mode on the
+CPU backend.
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import interpret_mode
 
 NEG_INF = -1e30
 
@@ -60,8 +63,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    q_chunk: int = 512, kv_chunk: int = 512,
-                    interpret: bool = True):
+                    q_chunk: int = 512, kv_chunk: int = 512):
     """q (BH, Sq, hd); k, v (BH, Sk, hd).  Returns (BH, Sq, hd).
 
     GQA callers fold (B, KV, G) into BH and broadcast K/V beforehand."""
@@ -93,12 +95,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((qc, 1), jnp.float32),
             pltpu.VMEM((qc, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(q, k, v)
 
 
 def flash_attention_gqa(q, k, v, *, causal=True, window=None,
-                        interpret: bool = True, q_chunk=512, kv_chunk=512):
+                        q_chunk=512, kv_chunk=512):
     """q (B,Sq,H,hd), k/v (B,Sk,KV,hd) -> (B,Sq,H,hd) via the kernel."""
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -107,5 +109,5 @@ def flash_attention_gqa(q, k, v, *, causal=True, window=None,
     kf = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Sk, hd)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Sk, hd)
     o = flash_attention(qf, kf, vf, causal=causal, window=window,
-                        interpret=interpret, q_chunk=q_chunk, kv_chunk=kv_chunk)
+                        q_chunk=q_chunk, kv_chunk=kv_chunk)
     return o.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
 from .relayout import _eff_d_buf
 
 
@@ -32,7 +33,7 @@ def _kernel(x_ref, w_ref, o_ref, *, tm: int, tn: int, d: int, eps: float,
 
 
 def rmsnorm_relayout(x: jnp.ndarray, weight, tile_shape, *, eps: float = 1e-6,
-                     d_buf: int = 9, interpret: bool = True) -> jnp.ndarray:
+                     d_buf: int = 9) -> jnp.ndarray:
     m, n = x.shape
     tm, tn = tile_shape
     gm, gn = m // tm, n // tn
@@ -50,5 +51,5 @@ def rmsnorm_relayout(x: jnp.ndarray, weight, tile_shape, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((d, gn, tm, tn), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((gm, gn, tm, tn), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, w)

@@ -21,14 +21,13 @@ __all__ = ["relayout", "rmsnorm_relayout", "quantize_tiled"]
 
 
 def relayout(x: jnp.ndarray, *, src_layout: L.Layout, dst_layout: L.Layout,
-             transpose: bool = False, d_buf: int = 9,
-             interpret: bool = True) -> jnp.ndarray:
+             transpose: bool = False, d_buf: int = 9) -> jnp.ndarray:
     logical = src_layout.logical_shape(x.shape)
     plan, reason = agu.plan_relayout(src_layout, dst_layout, logical,
                                      transpose=transpose, d_buf=d_buf)
     if plan is not None:
         agu.record_plan(plan)
-        return plan.run(x, interpret=interpret)
+        return plan.run(x)
     agu.record_fallback(reason)
     # fallback: logical-path relayout (XLA fuses it into one stream)
     v = src_layout.to_logical(x)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
 from .relayout import _eff_d_buf
 
 
@@ -24,8 +25,7 @@ def _kernel(x_ref, v_ref, s_ref, *, tm: int, tn: int, d: int, n: int):
     s_ref[...] = scale
 
 
-def quantize_tiled(x: jnp.ndarray, tile_shape=(32, 128), *, d_buf: int = 9,
-                   interpret: bool = True):
+def quantize_tiled(x: jnp.ndarray, tile_shape=(32, 128), *, d_buf: int = 9):
     m, n = x.shape
     tm, tn = tile_shape
     gm, gn = m // tm, n // tn
@@ -43,6 +43,6 @@ def quantize_tiled(x: jnp.ndarray, tile_shape=(32, 128), *, d_buf: int = 9,
             jax.ShapeDtypeStruct((gm, gn, tm, tn), jnp.int8),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x)
     return values, scales

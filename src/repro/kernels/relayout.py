@@ -49,33 +49,32 @@ def _tiled(tile_shape: Tuple[int, int]) -> L.Layout:
     return L.tiled_layout(*tile_shape)
 
 
-def tile(x: jnp.ndarray, tile_shape: Tuple[int, int], *, d_buf: int = 9,
-         interpret: bool = True) -> jnp.ndarray:
+def tile(x: jnp.ndarray, tile_shape: Tuple[int, int], *,
+         d_buf: int = 9) -> jnp.ndarray:
     """MN -> MNMtmNtn (Prefill 2)."""
     return agu_relayout(x, src_layout=L.MN, dst_layout=_tiled(tile_shape),
-                        d_buf=d_buf, interpret=interpret)
+                        d_buf=d_buf)
 
 
-def untile(x: jnp.ndarray, *, d_buf: int = 9, interpret: bool = True) -> jnp.ndarray:
+def untile(x: jnp.ndarray, *, d_buf: int = 9) -> jnp.ndarray:
     """MNMtmNtn -> MN (Prefill 1); the tile geometry comes from the buffer."""
     tm, tn = x.shape[-2], x.shape[-1]
     return agu_relayout(x, src_layout=_tiled((tm, tn)), dst_layout=L.MN,
-                        d_buf=d_buf, interpret=interpret)
+                        d_buf=d_buf)
 
 
-def tiled_transpose(x: jnp.ndarray, *, d_buf: int = 9,
-                    interpret: bool = True) -> jnp.ndarray:
+def tiled_transpose(x: jnp.ndarray, *, d_buf: int = 9) -> jnp.ndarray:
     """MNMtmNtn -> MNMtmNtn, logically transposed (the KV-cache Load op)."""
     gm, gn, tm, tn = x.shape
     lay = _tiled((tm, tn))
     return agu_relayout(x, src_layout=lay, dst_layout=lay, transpose=True,
-                        d_buf=d_buf, interpret=interpret)
+                        d_buf=d_buf)
 
 
-def mn_transpose(x: jnp.ndarray, *, block: int = 128, d_buf: int = 9,
-                 interpret: bool = True) -> jnp.ndarray:
+def mn_transpose(x: jnp.ndarray, *, block: int = 128,
+                 d_buf: int = 9) -> jnp.ndarray:
     """MN -> MN, transposed.  ``block`` is retained for API compatibility;
     the AGU planner picks the superblock from the pattern."""
     del block
     return agu_relayout(x, src_layout=L.MN, dst_layout=L.MN, transpose=True,
-                        d_buf=d_buf, interpret=interpret)
+                        d_buf=d_buf)

@@ -28,8 +28,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}; found {len(devs)}. "
             "The dry-run launcher sets XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax.")
-    from repro.sharding import make_mesh_compat
-    return make_mesh_compat(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def axes_for(mesh: Mesh, shape: ShapeConfig) -> Axes:

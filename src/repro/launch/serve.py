@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.data.pipeline import SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.engine import ServingEngine
 
@@ -30,6 +31,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=12)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
     params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)

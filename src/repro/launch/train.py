@@ -24,6 +24,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import SyntheticLM
 from repro.launch import mesh as MM
+from repro.launch.cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.step import init_state, make_train_step
 
@@ -66,8 +67,8 @@ def train(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
             if n_dev % m == 0:
                 model = m
                 break
-        from repro.sharding import make_mesh_compat
-        mesh = make_mesh_compat((n_dev // model, model), ("data", "model"))
+        mesh = jax.make_mesh((n_dev // model, model), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape_tmp = ShapeConfig("cli", seq, batch, "train", microbatches)
         cfg = cfg.with_axes(MM.axes_for(mesh, shape_tmp))
         cfg = dataclasses.replace(cfg, fsdp=True)
@@ -147,6 +148,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     _, history = train(args.arch, steps=args.steps, batch=args.batch,
                        seq=args.seq, smoke=args.smoke, ckpt_dir=args.ckpt_dir,
                        ckpt_every=args.ckpt_every,

@@ -221,14 +221,13 @@ class DistributedScheduler:
     the oldest pending task always sits dep-satisfied at its ring head and
     every round retires at least one descriptor."""
 
-    def __init__(self, topology: Topology, *, interpret: bool = True,
+    def __init__(self, topology: Topology, *,
                  name: str = "sched", ring_depth: int = DEFAULT_RING_DEPTH,
                  backpressure: str = "block"):
         if backpressure not in ("block", "error"):
             raise ValueError(f"backpressure must be 'block' or 'error', "
                              f"got {backpressure!r}")
         self.topology = topology
-        self.interpret = interpret
         self.name = name
         self.ring_depth = int(ring_depth)
         self.backpressure = backpressure
@@ -591,12 +590,10 @@ class DistributedScheduler:
             # One batched XLA program for the round: the cached per-descriptor
             # lowerings are inlined into a single jitted tuple program, cached
             # by the round's descriptor identities.
-            key = tuple((ready[i].desc.cache_key(), self.interpret)
-                        for i in batch)
+            key = tuple(ready[i].desc.cache_key() for i in batch)
             fused = _ROUND_CACHE.get(key)
             if fused is None:
-                fns = tuple(_api._lowered(ready[i].desc, self.interpret)
-                            for i in batch)
+                fns = tuple(_api._lowered(ready[i].desc) for i in batch)
                 fused = jax.jit(lambda xs, _fns=fns:
                                 tuple(f(x) for f, x in zip(_fns, xs)))
                 _ROUND_CACHE[key] = fused
@@ -613,7 +610,7 @@ class DistributedScheduler:
         for i, t in enumerate(ready):
             if i not in fused_ids:
                 if t.kind == "xdma":
-                    t.value = _api._lowered(t.desc, self.interpret)(inputs[i])
+                    t.value = _api._lowered(t.desc)(inputs[i])
                 else:
                     t.value = t.fn(*(self._resolve(a) for a in t.inputs))
             if t.nbytes is None:

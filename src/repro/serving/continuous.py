@@ -399,12 +399,17 @@ class ContinuousBatchingEngine:
             active.append(st)
             restored.append(st)
         admitted = []
+        # prompt pages are allocated after prefill, so this step's earlier
+        # admissions still count against the free pool
+        budget = self.pool.free_pages
         while queue and len(active) < self.max_batch:
             st = queue[0]
             if st.req.arrival_s > clock:
                 break
-            if self._footprint(st.req.prompt_len) > self.pool.free_pages:
+            need = self._footprint(st.req.prompt_len)
+            if need > budget:
                 break
+            budget -= need
             queue.pop(0)
             st.status = "active"
             active.append(st)

@@ -18,33 +18,7 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["Axes", "constrain", "P", "shard_map_compat", "make_mesh_compat"]
-
-
-def make_mesh_compat(axis_shape, axis_names, *, devices=None):
-    """jax.make_mesh across versions: pass Auto axis_types where the API has
-    them (the default on new jax), plain mesh construction where it doesn't."""
-    kw = {"devices": devices} if devices is not None else {}
-    try:
-        return jax.make_mesh(
-            axis_shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(axis_shape, axis_names, **kw)
-
-
-def shard_map_compat(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions: new API (jax.shard_map, check_vma) or
-    the experimental one (check_rep).  Replication checking is disabled on
-    both — the XDMA collectives intentionally mix manual axes."""
-    try:
-        from jax import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+__all__ = ["Axes", "constrain", "P"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,7 +65,7 @@ def constrain(x, spec: P):
     mesh = _ambient_mesh()
     if mesh is None:
         return x
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)      # concrete and abstract meshes alike
     parts = (tuple(spec) + (None,) * x.ndim)[:x.ndim]
     clamped = []
     for i, ax in enumerate(parts):

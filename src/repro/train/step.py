@@ -27,7 +27,7 @@ from repro.core import api as xdma
 from repro.core.descriptor import reduce_descriptor
 from repro.models import lm
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
-from repro.sharding import constrain, P, shard_map_compat
+from repro.sharding import constrain, P
 
 
 class TrainState(dict):
@@ -181,10 +181,10 @@ def make_dp_train_step(cfg: ModelConfig, shape: ShapeConfig,
 
     # jit around the shard_map (eager shard_map cannot evaluate closed
     # calls); the capture chokepoints record at trace time either way
-    sharded = jax.jit(shard_map_compat(
-        body, mesh,
+    sharded = jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(axis)),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()), check_vma=False))
 
     def train_step(state, batch):
         loss, grads = sharded(state["params"], batch)

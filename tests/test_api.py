@@ -288,13 +288,15 @@ perm = tuple((i, (i+1) % 8) for i in range(8))
 q = C.XDMAQueue([C.describe('MN', 'MN', C.Scale(2.0)),
                  C.describe(C.MN, Endpoint.peer('x', perm))], name='mixed')
 assert not q.is_local
-run = shard_map_compat(lambda xs: q.run(xs), mesh, PS('x'), PS('x'))(x)
+run = jax.shard_map(lambda xs: q.run(xs), mesh=mesh, in_specs=PS('x'),
+                    out_specs=PS('x'), check_vma=False)(x)
 def chain(xs):
     v = xs
     for i in range(len(q)):
         v = q.run_task(v, i)
     return v
-stepped = shard_map_compat(chain, mesh, PS('x'), PS('x'))(x)
+stepped = jax.shard_map(chain, mesh=mesh, in_specs=PS('x'),
+                        out_specs=PS('x'), check_vma=False)(x)
 np.testing.assert_array_equal(np.asarray(run), np.asarray(stepped))
 np.testing.assert_allclose(np.asarray(run),
                            np.asarray(jnp.roll(2.0 * x, 1, axis=0)),
@@ -331,8 +333,8 @@ from jax.sharding import PartitionSpec as PS
 from repro import core as C
 from repro.core import xdma
 from repro.core.descriptor import Endpoint
-from repro.sharding import shard_map_compat
-mesh = jax.make_mesh((8,), ('x',))
+mesh = jax.make_mesh((8,), ('x',),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 """
 
 
@@ -342,11 +344,13 @@ x = jnp.asarray(np.random.default_rng(2).standard_normal((8, 16, 128)), jnp.floa
 perm = tuple((i, (i+1) % 8) for i in range(8))
 desc = C.describe(Endpoint.local(C.MN), Endpoint.peer('x', perm),
                   pre=(C.Quantize(),), post=(C.Dequantize(jnp.float32),))
-new = shard_map_compat(lambda xs: xdma.transfer(xs, desc), mesh, PS('x'), PS('x'))(x)
-old = shard_map_compat(lambda xs: C.xdma_ppermute(xs, 'x', list(perm),
+new = jax.shard_map(lambda xs: xdma.transfer(xs, desc), mesh=mesh,
+                    in_specs=PS('x'), out_specs=PS('x'), check_vma=False)(x)
+old = jax.shard_map(lambda xs: C.xdma_ppermute(xs, 'x', list(perm),
                                                   pre=[C.Quantize()],
                                                   post=[C.Dequantize(jnp.float32)]),
-                       mesh, PS('x'), PS('x'))(x)
+                       mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                       check_vma=False)(x)
 np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 np.testing.assert_allclose(np.asarray(new), np.asarray(jnp.roll(x, 1, axis=0)),
                            rtol=0.02, atol=0.02)
@@ -359,11 +363,13 @@ def test_transfer_all_to_all_parity():
     out = run_multidevice(_REMOTE_PRELUDE + """
 x = jnp.asarray(np.random.default_rng(3).standard_normal((8, 8, 4, 16)), jnp.float32)
 desc = C.describe(Endpoint.local(C.MN), Endpoint.all_to_all('x', 0, 1))
-new = shard_map_compat(lambda xs: xdma.transfer(xs[0], desc)[None],
-                       mesh, PS('x'), PS('x'))(x)
-old = shard_map_compat(lambda xs: C.xdma_all_to_all(xs[0], 'x',
+new = jax.shard_map(lambda xs: xdma.transfer(xs[0], desc)[None],
+                       mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                       check_vma=False)(x)
+old = jax.shard_map(lambda xs: C.xdma_all_to_all(xs[0], 'x',
                                                     split_axis=0, concat_axis=1)[None],
-                       mesh, PS('x'), PS('x'))(x)
+                       mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                       check_vma=False)(x)
 np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 print('OK')
 """)
@@ -375,10 +381,12 @@ def test_transfer_reduce_parity_with_compressed_psum():
 g = jnp.asarray(np.random.default_rng(1).standard_normal((8, 1000)), jnp.float32)
 desc = C.describe(Endpoint.local(C.MN), Endpoint.reduce('x', axis_size=8),
                   pre=(C.Quantize(),), post=(C.Dequantize(jnp.float32),))
-new = shard_map_compat(lambda gs: xdma.transfer(gs[0], desc)[None],
-                       mesh, PS('x'), PS('x'))(g)
-old = shard_map_compat(lambda gs: C.compressed_psum(gs[0], 'x', 8)[None],
-                       mesh, PS('x'), PS('x'))(g)
+new = jax.shard_map(lambda gs: xdma.transfer(gs[0], desc)[None],
+                       mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                       check_vma=False)(g)
+old = jax.shard_map(lambda gs: C.compressed_psum(gs[0], 'x', 8)[None],
+                       mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                       check_vma=False)(g)
 np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 rel = float(jnp.abs(new[0] - g.sum(0)).max() / jnp.abs(g.sum(0)).max())
 assert rel < 0.02, rel
@@ -386,16 +394,18 @@ assert rel < 0.02, rel
 desc2 = C.describe(Endpoint.local(C.MN), Endpoint.reduce('x', axis_size=8),
                    pre=(C.Scale(2.0), C.Quantize()),
                    post=(C.Dequantize(jnp.float32),))
-scaled = shard_map_compat(lambda gs: xdma.transfer(gs[0], desc2)[None],
-                          mesh, PS('x'), PS('x'))(g)
+scaled = jax.shard_map(lambda gs: xdma.transfer(gs[0], desc2)[None],
+                          mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                          check_vma=False)(g)
 assert scaled.dtype == jnp.float32
 rel2 = float(jnp.abs(scaled[0] - 2.0 * g.sum(0)).max() / jnp.abs(2.0 * g.sum(0)).max())
 assert rel2 < 0.02, rel2
 # uncompressed reduce: plain psum with host plugins
 desc3 = C.describe(Endpoint.local(C.MN), Endpoint.reduce('x', axis_size=8),
                    post=(C.BiasAdd(1.0),))
-plain = shard_map_compat(lambda gs: xdma.transfer(gs[0], desc3)[None],
-                         mesh, PS('x'), PS('x'))(g)
+plain = jax.shard_map(lambda gs: xdma.transfer(gs[0], desc3)[None],
+                         mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                         check_vma=False)(g)
 np.testing.assert_allclose(np.asarray(plain[0]), np.asarray(g.sum(0) + 1.0),
                            rtol=1e-5, atol=1e-5)
 # a Dequantize with no matching pre Quantize is not a wire codec: it stays on
@@ -403,8 +413,9 @@ np.testing.assert_allclose(np.asarray(plain[0]), np.asarray(g.sum(0) + 1.0),
 desc4 = C.describe(Endpoint.local(C.MN), Endpoint.reduce('x', axis_size=8),
                    post=(C.Dequantize(jnp.bfloat16),))
 try:
-    shard_map_compat(lambda gs: xdma.transfer(gs[0], desc4)[None],
-                     mesh, PS('x'), PS('x'))(g)
+    jax.shard_map(lambda gs: xdma.transfer(gs[0], desc4)[None],
+                     mesh=mesh, in_specs=PS('x'), out_specs=PS('x'),
+                     check_vma=False)(g)
 except Exception:
     pass
 else:
@@ -427,7 +438,8 @@ cfg = dataclasses.replace(configs.smoke_config('qwen3_moe_30b_a3b'),
 p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model), jnp.float32)
 y_local, aux_local = MOE.moe_apply(cfg, p, x)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg2 = cfg.with_axes(Axes(batch=('data',), model='model', model_size=4, batch_size=2))
 with mesh:
     y_dist, aux_dist = jax.jit(lambda xx: MOE.moe_apply(cfg2, p, xx, mesh=mesh))(x)

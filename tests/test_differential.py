@@ -30,7 +30,7 @@ import oracle as O
 from repro import core as C
 from repro.core import plugins as P
 from repro.core import xdma
-from repro.sharding import shard_map_compat, P as Pspec
+from repro.sharding import P as Pspec
 
 # -- the generation space ----------------------------------------------------
 MS = (128, 256, 384)
@@ -194,8 +194,9 @@ def run_transfer(x, desc):
     """xdma.transfer, inside a size-1 shard_map for remote movements."""
     if desc.movement == "local":
         return xdma.transfer(x, desc)
-    fn = shard_map_compat(lambda v: xdma.transfer(v, desc), _mesh(),
-                          (Pspec("m"),), Pspec("m"))
+    fn = jax.shard_map(lambda v: xdma.transfer(v, desc), mesh=_mesh(),
+                       in_specs=(Pspec("m"),), out_specs=Pspec("m"),
+                       check_vma=False)
     return fn(x)
 
 
@@ -203,9 +204,11 @@ def check_against_oracle(case: DescCase):
     x, desc = case.build()
     got = run_transfer(x, desc)
     want = O.oracle_transfer(x, desc)
-    O.assert_matches(got, want, context=repr(case), **O.chain_tolerance(desc))
-    # compile-time contracts agree with what executed
     logical_in = desc.src.layout.logical_shape(x.shape)
+    logical = O.to_logical(np.asarray(x), desc.src.layout)
+    O.assert_matches(got, want, context=repr(case),
+                     **O.chain_tolerance(desc, logical=logical))
+    # compile-time contracts agree with what executed
     out_logical = desc.out_logical_shape(logical_in)
     values = got.values if isinstance(got, (P.QTensor, P.CTensor)) else got
     assert values.shape == desc.dst.layout.physical_shape(out_logical), repr(case)

@@ -350,7 +350,7 @@ from repro import configs
 from repro.layers import moe as MOE
 from repro.models import lm
 from repro.serving import ContinuousBatchingEngine, PagedKVPool, uniform_stream
-from repro.sharding import P, shard_map_compat
+from repro.sharding import P
 
 # put the serving plane under real page pressure first
 cfg = dataclasses.replace(configs.smoke_config('qwen3_1p7b'),
@@ -363,15 +363,16 @@ rep = ContinuousBatchingEngine(cfg, params, max_len=24, max_batch=3,
 assert rep.preemptions > 0, 'pool of 7 pages must force preemption'
 
 # ...and the multicast-backed ring all-gather must still be bitwise
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 def body(v):
     return (MOE._ring_all_gather(v, 'model', 4),
             lax.all_gather(v, 'model', axis=1, tiled=True))
 v = jax.random.normal(jax.random.PRNGKey(2), (8, 4, 16), jnp.float32)
 with mesh:
-    ring, ref = jax.jit(shard_map_compat(
-        body, mesh, in_specs=P(None, 'model', None),
-        out_specs=P(None, 'model', None)))(v)
+    ring, ref = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=P(None, 'model', None),
+        out_specs=P(None, 'model', None), check_vma=False))(v)
 np.testing.assert_array_equal(np.asarray(ring), np.asarray(ref))
 print('MCAST_AG_OK', rep.preemptions)
 """, n_devices=8)

@@ -149,6 +149,23 @@ def test_continuous_parity_survives_preemption(model):
         np.testing.assert_array_equal(rep.tokens[r.rid], ref[r.rid])
 
 
+def test_admission_counts_pages_of_same_step_admissions(model):
+    """Each prompt alone fits the pool but three together do not: admission
+    must charge the pages of requests it already admitted this step (their
+    pages are allocated only after prefill), or prefill runs the pool out."""
+    cfg, params = model
+    reqs = uniform_stream(cfg, 3, 0.0, prompt_len=8, max_new=4)
+    ref = _reference_tokens(cfg, params, reqs, 24, 4)
+    eng = ContinuousBatchingEngine(cfg, params, max_len=24, max_batch=3,
+                                   cache_dtype=jnp.float32,
+                                   pool=PagedKVPool(5, 32))
+    rep = eng.serve(reqs)
+    assert rep.n_requests == 3
+    assert rep.pool_stats["peak_used"] <= 5
+    for r in reqs:
+        np.testing.assert_array_equal(rep.tokens[r.rid], ref[r.rid])
+
+
 def test_ragged_batch_tokens_independent_of_composition(model):
     """Staggered arrivals make a ragged (vector-position) batch; each
     request's tokens must equal the ones it gets served alone (batch

@@ -1,4 +1,6 @@
 """Plugin semantics + engine/baseline agreement (hypothesis where useful)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -187,3 +189,29 @@ def test_plugin_compiler_cfg_stats():
     assert stats["fused"] >= 1 and stats["fallback"] >= 2
     assert any(r.startswith("no-emit:quantize") for r in stats["reasons"])
     assert "empty-chain" in stats["reasons"]
+    # a chain still compressed at its end would write a rank-1 bool mask,
+    # which Mosaic cannot: refused by rule, never compiled
+    ct = xdma.transfer(x, C.describe("MN", "MN", C.Compress(block_rows=8)))
+    assert isinstance(ct, C.CTensor)
+    assert "payload-output:compress_blocksparse" in PC.cfg_stats()["reasons"]
+    # a whole-block kernel larger than VMEM takes the XLA composition, per
+    # input shape, with the same bits
+    big = rand((1024, 1024), 3)
+    perm = np.arange(1023, -1, -1)
+    desc = C.describe("MN", "MN", C.GatherScatter(indices=perm))
+    got = xdma.transfer(big, desc)
+    assert PC.cfg_stats()["reasons"].get("vmem-block") == 1
+    want = xdma.transfer(big, dataclasses.replace(desc, backend="fused"))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_plugin_compiler_squeezes_unit_leading_dims():
+    """A (1, M, N) movement runs the (M, N) kernel: same bytes, same bits."""
+    from repro.core import xdma
+    x = rand((64, 256), 5)[None]
+    for desc in (C.describe("MN", "MNM8N128", C.Scale(1.25)),
+                 C.describe("MN", "MN", C.Transpose())):
+        got = xdma.transfer(x, desc)
+        want = xdma.transfer(x, dataclasses.replace(desc, backend="fused"))
+        assert got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

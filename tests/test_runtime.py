@@ -360,7 +360,8 @@ cfg = dataclasses.replace(configs.smoke_config('qwen3_moe_30b_a3b'),
 p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model), jnp.float32)
 y_local, _ = MOE.moe_apply(cfg, p, x)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg2 = cfg.with_axes(Axes(batch=('data',), model='model', model_size=4, batch_size=2))
 sched = DistributedScheduler(Topology.parallel(2, prefix='a2a'), name='moe')
 with mesh:

@@ -60,8 +60,8 @@ def test_kv_cache_spec_rules():
 
 
 def test_fit_specs_drops_nondivisible():
-    from repro.sharding import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     # fake mesh with model=1 divides everything; use shape check instead
     specs = {"a": P("model"), "b": P("model")}
     shapes = {"a": jax.ShapeDtypeStruct((7,), jnp.float32),
@@ -75,8 +75,8 @@ def test_axes_for_shapes():
     pytest.importorskip("jax")
     from repro.configs.base import SHAPES
     # long_500k on a fake 4x4 mesh: batch=1 -> context parallel on data
-    from repro.sharding import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ax = MM.axes_for(mesh, SHAPES["long_500k"])
     assert ax.seq == "data" and ax.batch == ()
     ax2 = MM.axes_for(mesh, SHAPES["train_4k"])

@@ -153,7 +153,8 @@ def test_three_way_parity_moe_capture():
     p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model),
                           jnp.float32)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     cfg = cfg.with_axes(Axes(batch=(), model="model", model_size=1,
                              batch_size=1))
     sched = DistributedScheduler(Topology.parallel(2, prefix="a2a"),
@@ -186,7 +187,8 @@ def test_three_way_parity_train_capture(model):
     shape = ShapeConfig("t", 16, 4, "train", microbatches=1)
     ds = SyntheticLM(vocab=cfg.vocab, seq_len=16, global_batch=4, seed=0)
     state = init_state(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((1,), ("dp",))
+    mesh = jax.make_mesh((1,), ("dp",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     step = make_dp_train_step(cfg, shape, mesh=mesh, axis="dp",
                               compressed=True)
     telemetry.reset("links")

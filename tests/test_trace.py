@@ -269,14 +269,15 @@ import dataclasses, jax, jax.numpy as jnp, numpy as np
 from jax import lax
 from repro import configs
 from repro.layers import moe as MOE
-from repro.sharding import Axes, P, shard_map_compat
+from repro.sharding import Axes, P
 from repro.runtime import capture
 
 cfg = dataclasses.replace(configs.smoke_config('qwen3_moe_30b_a3b'),
                           dtype=jnp.float32, capacity_factor=8.0)
 p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model), jnp.float32)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg2 = cfg.with_axes(Axes(batch=('data',), model='model', model_size=4,
                           batch_size=2))
 
@@ -302,9 +303,9 @@ def body(v):
     return g_ring, g_ref
 v = jax.random.normal(jax.random.PRNGKey(2), (8, 4, 16), jnp.float32)
 with mesh:
-    ring, ref = jax.jit(shard_map_compat(
-        body, mesh, in_specs=P(None, 'model', None),
-        out_specs=P(None, 'model', None)))(v)
+    ring, ref = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=P(None, 'model', None),
+        out_specs=P(None, 'model', None), check_vma=False))(v)
 np.testing.assert_array_equal(np.asarray(ring), np.asarray(ref))
 
 # TP path (psum through a reduce descriptor) matches replicated-expert math
@@ -336,7 +337,8 @@ cfg = dataclasses.replace(configs.smoke_config('qwen2_0p5b'), dtype=jnp.float32)
 shape = ShapeConfig('t', 16, 8, 'train', microbatches=1)
 ds = SyntheticLM(vocab=cfg.vocab, seq_len=16, global_batch=8, seed=1)
 state = init_state(jax.random.PRNGKey(0), cfg)
-mesh = jax.make_mesh((4,), ('dp',))
+mesh = jax.make_mesh((4,), ('dp',),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 
 # uncompressed explicit DP == the single-process reference step
 step_ref = jax.jit(make_train_step(cfg, shape))
