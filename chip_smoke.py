@@ -298,8 +298,9 @@ def one_chip(seed: int) -> dict:
     log(f"peak_bytes_in_use: {device.memory_stats()['peak_bytes_in_use']}")
     for bank in ("agu", "plugin_compiler"):
         c = counters.get(bank, {})
+        counts = {k: v for k, v in c.items() if not k.startswith("reason:")}
         reasons = {k: v for k, v in c.items() if k.startswith("reason:")}
-        log(f"{bank}: fallback {c.get('fallback', 0)} {reasons}")
+        log(f"{bank}: fallback {c.get('fallback', 0)} {counts} {reasons}")
     return {"platform": device.platform, "kind": device.device_kind,
             "count": len(jax.devices())}
 

@@ -88,14 +88,14 @@ def xdma_copy_pallas(x: jnp.ndarray, desc: XDMADescriptor) -> jnp.ndarray:
     """
     from repro.kernels import agu, ops as kops  # local import: keep core importable w/o kernels
 
-    pure_transpose = (len(desc.plugins) == 1 and isinstance(desc.plugins[0], P.Transpose))
-    if desc.plugins and not pure_transpose:
+    transpose = kops.chain_transpose(desc.plugins)
+    if transpose is None:
         agu.record_fallback("plugin-chain")
         return xdma_copy(x, desc)
     return kops.relayout(
         x,
         src_layout=desc.src_layout,
         dst_layout=desc.dst_layout,
-        transpose=pure_transpose,
+        transpose=transpose,
         d_buf=desc.d_buf,
     )

@@ -28,10 +28,11 @@ pytree (``Compress`` not followed by ``Decompress``: Mosaic cannot write the
 rank-1 bool mask), falls back to the fused-XLA composition —
 behaviour is identical by construction and enforced bitwise by the
 differential harness (``tests/test_differential.py``).  :func:`cfg_stats`
-reports how many CFG phases fused vs fell back, and why.
+reports how many lowerings fused vs fell back, and why.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -45,18 +46,20 @@ from . import layouts as L
 from . import plugins as P
 from .descriptor import XDMADescriptor
 
-__all__ = ["can_fuse", "compile_local", "compile_side", "maybe_compile_local",
+__all__ = ["can_fuse", "compile_local", "maybe_compile_local",
            "maybe_compile_side", "cfg_stats", "clear_stats"]
 
 
-# -- fusion accounting (one event per CFG phase, not per Data phase) ---------
+# -- fusion accounting (one event per lowering, not per Data phase) ----------
+# A refused chain is one event at its CFG phase; a fusible chain is one event
+# per input shape, where its kernel (or the vmem-block fallback) is chosen.
 # Counters live in telemetry.bank("plugin_compiler"); this module keeps the
 # historical view functions.
 _BANK = _tm.bank("plugin_compiler")
 
 
 def cfg_stats() -> Dict[str, Any]:
-    """Fused vs fallback CFG-phase counts, with per-reason fallback detail.
+    """Fused vs fallback lowering counts, with per-reason fallback detail.
 
     .. deprecated:: PR 7
         Thin view over ``telemetry.bank("plugin_compiler")`` — prefer
@@ -157,7 +160,7 @@ def _out_struct(in_aval, src_layout, chain):
     return jax.eval_shape(f, in_aval)
 
 
-def _compile_block(chain, src_layout, dst_layout, in_aval, interpret):
+def _compile_block(chain, src_layout, dst_layout, in_aval):
     """Whole-array template: one grid step, full blocks through VMEM.  The
     chain's output is a plain array (:func:`_chain_fusible` keeps payload
     pytrees inside the kernel)."""
@@ -175,7 +178,7 @@ def _compile_block(chain, src_layout, dst_layout, in_aval, interpret):
         kernel,
         out_shape=jax.ShapeDtypeStruct(
             dst_layout.physical_shape(struct.shape), struct.dtype),
-        interpret=interpret_mode(interpret))
+        interpret=interpret_mode())
     return lambda x: call(x, *consts)
 
 
@@ -195,7 +198,7 @@ def _burst_rows(chain, src_layout, dst_layout, m: int, d_buf: int) -> Optional[i
     return base * eff_d_buf(m // base, d_buf)
 
 
-def _compile_streamed(chain, src_layout, dst_layout, in_aval, d_buf, interpret):
+def _compile_streamed(chain, src_layout, dst_layout, in_aval, d_buf):
     """Row-burst template for all-streaming chains (d_buf-deep bursts)."""
     from repro.kernels import interpret_mode
     from repro.kernels.agu import slab_spec
@@ -230,7 +233,7 @@ def _compile_streamed(chain, src_layout, dst_layout, in_aval, d_buf, interpret):
         out_specs=spec(dst_layout, n),
         out_shape=jax.ShapeDtypeStruct(dst_layout.physical_shape((m, n)),
                                        out_dtype),
-        interpret=interpret_mode(interpret),
+        interpret=interpret_mode(),
     )
     return lambda x: call(x, *consts)
 
@@ -266,47 +269,52 @@ def _composition(chain, src_layout, dst_layout):
         P.apply_chain(chain, _read_stage(x, src_layout)), dst_layout)
 
 
-def _compile_for_aval(chain, src_layout, dst_layout, d_buf, in_aval, interpret):
-    """Pick the kernel for one input shape.  In order: squeeze unit leading
-    dims; a lone ``Transpose`` is the AGU's transposing relayout (gridded
-    superblocks, so any size fits VMEM); all-streaming chains take the
-    row-burst template; the rest take the whole-block template when it fits
-    VMEM, else the XLA composition (tallied as ``vmem-block``)."""
+def _compile_for_aval(chain, src_layout, dst_layout, d_buf, in_aval):
+    """Pick the kernel for one input shape -> (callable, fallback reason or
+    None).  In order: squeeze unit leading dims; a lone ``Transpose`` is the
+    AGU's transposing relayout (gridded superblocks, so any size fits VMEM);
+    all-streaming chains take the row-burst template; the rest take the
+    whole-block template when it fits VMEM, else the XLA composition
+    (reason ``vmem-block``)."""
     from repro.kernels import agu
+    from repro.kernels import ops as kops
     logical = src_layout.logical_shape(in_aval.shape)
     out_logical = P.chain_out_shape(chain, logical)
     k = _unit_lead(chain, src_layout, dst_layout, logical, out_logical)
     if k:
         inner_shape = src_layout.physical_shape(logical[k:])
-        inner = _compile_for_aval(
+        inner, reason = _compile_for_aval(
             chain, src_layout, dst_layout, d_buf,
-            jax.ShapeDtypeStruct(inner_shape, in_aval.dtype), interpret)
+            jax.ShapeDtypeStruct(inner_shape, in_aval.dtype))
         out_shape = dst_layout.physical_shape(out_logical)
-        return lambda x: inner(x.reshape(inner_shape)).reshape(out_shape)
-    if (len(chain) == 1 and isinstance(chain[0], P.Transpose)
-            and len(logical) == 2):
+        return (lambda x: inner(x.reshape(inner_shape)).reshape(out_shape),
+                reason)
+    if kops.chain_transpose(chain) and len(logical) == 2:
         plan, _ = agu.plan_relayout(src_layout, dst_layout, logical,
                                     transpose=True, d_buf=d_buf)
         if plan is not None:
-            return lambda x: plan.run(x, interpret=interpret)
+            return functools.partial(kops.relayout, src_layout=src_layout,
+                                     dst_layout=dst_layout, transpose=True,
+                                     d_buf=d_buf), None
     if all(p.streaming for p in chain):
-        fn = _compile_streamed(chain, src_layout, dst_layout, in_aval,
-                               d_buf, interpret)
+        fn = _compile_streamed(chain, src_layout, dst_layout, in_aval, d_buf)
         if fn is not None:
-            return fn
+            return fn, None
     out_dtype = P.chain_out_dtype(chain, in_aval.dtype)
     block = (math.prod(in_aval.shape) * jnp.dtype(in_aval.dtype).itemsize
              + math.prod(out_logical) * jnp.dtype(out_dtype).itemsize
              + math.prod(logical) * 4)
     if block > _BLOCK_VMEM_BYTES:
-        _record(False, "vmem-block")
-        return _composition(chain, src_layout, dst_layout)
-    return _compile_block(chain, src_layout, dst_layout, in_aval, interpret)
+        return _composition(chain, src_layout, dst_layout), "vmem-block"
+    return _compile_block(chain, src_layout, dst_layout, in_aval), None
 
 
-def _specializing(chain, src_layout, dst_layout, d_buf, interpret, validate):
+def _specializing(chain, src_layout, dst_layout, d_buf, validate, *,
+                  strict: bool):
     """Descriptor-level callable: specializes one kernel per input aval
-    (mirroring how jit caches executables by shape under the CFG cache)."""
+    (mirroring how jit caches executables by shape under the CFG cache) and
+    tallies each specialization as fused or fallback.  ``strict`` (a forced
+    kernel) raises where the XLA composition would stand in."""
     kernels: Dict[Tuple, Callable] = {}
 
     def run(x):
@@ -316,8 +324,12 @@ def _specializing(chain, src_layout, dst_layout, d_buf, interpret, validate):
         fn = kernels.get(key)
         if fn is None:
             validate(aval)
-            fn = _compile_for_aval(chain, src_layout, dst_layout, d_buf,
-                                   aval, interpret)
+            fn, reason = _compile_for_aval(chain, src_layout, dst_layout,
+                                           d_buf, aval)
+            if strict and reason is not None:
+                raise ValueError(f"descriptor is not fusible at {x.shape} "
+                                 f"({reason}); use the fused-XLA backend")
+            _record(reason is None, reason or "")
             kernels[key] = fn
         return fn(x)
 
@@ -325,9 +337,17 @@ def _specializing(chain, src_layout, dst_layout, d_buf, interpret, validate):
 
 
 # -- public entry points -----------------------------------------------------
-def compile_local(desc: XDMADescriptor, *,
-                  interpret: Optional[bool] = None) -> Callable:
-    """The full local datapath as one kernel; raises when not fusible.
+def _local(desc: XDMADescriptor, *, strict: bool) -> Callable:
+    def validate(aval):
+        desc.validate(desc.src.layout.logical_shape(aval.shape))
+
+    return _specializing(desc.pre + desc.post, desc.src.layout,
+                         desc.dst.layout, desc.d_buf, validate, strict=strict)
+
+
+def compile_local(desc: XDMADescriptor) -> Callable:
+    """The full local datapath as one kernel; raises when not fusible, or
+    (at its first call with a shape) when no kernel fits that shape.
 
     The returned callable specializes (and memoizes) one ``pallas_call`` per
     input shape/dtype — wrap it in ``jax.jit`` for the usual CFG caching.
@@ -335,59 +355,42 @@ def compile_local(desc: XDMADescriptor, *,
     if desc.movement != "local":
         raise ValueError(f"compile_local only lowers local movements, "
                          f"got {desc.movement!r}")
-    chain = desc.pre + desc.post
-    reason = _chain_fusible(chain)
+    reason = _chain_fusible(desc.pre + desc.post)
     if reason is not None:
         raise ValueError(f"descriptor is not fusible ({reason}); "
                          "use the fused-XLA backend instead")
-
-    def validate(aval):
-        desc.validate(desc.src.layout.logical_shape(aval.shape))
-
-    return _specializing(chain, desc.src.layout, desc.dst.layout, desc.d_buf,
-                         interpret, validate)
+    return _local(desc, strict=True)
 
 
 def maybe_compile_local(desc: XDMADescriptor) -> Optional[Callable]:
     """``backend='auto'`` policy + stats: the compiled datapath, or None to
-    signal the XLA-composition fallback."""
+    signal the XLA-composition fallback.  A fusible chain is tallied per
+    input shape, where its kernel is chosen; a refused one here, once."""
     ok, reason = can_fuse(desc)
-    _record(ok, reason)
     if not ok:
+        _record(False, reason)
         return None
-    return compile_local(desc)
+    return _local(desc, strict=False)
 
 
-def compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *, side: str,
-                 d_buf: int = 9,
-                 interpret: Optional[bool] = None) -> Callable:
-    """One endpoint side of a remote movement as a single kernel.
+def maybe_compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *,
+                       side: str, d_buf: int = 9) -> Optional[Callable]:
+    """One endpoint side of a remote movement as a single kernel, or None.
 
     ``side='src'``: reader + pre-chain (physical src buffer -> link payload);
     ``side='dst'``: post-chain + writer (link payload -> physical dst
-    buffer).  The identity layout stands in for the link end.
-    """
-    chain = tuple(chain)
-    reason = _chain_fusible(chain)
-    if reason is not None:
-        raise ValueError(f"side is not fusible ({reason})")
+    buffer).  The identity layout stands in for the link end.  A non-empty,
+    fully emit-capable chain whose payload stays a plain array fuses
+    (pytree payloads like QTensor/CTensor split the stream across the
+    collective).  Sides with no plugins don't count as fallbacks — there is
+    no chain to fuse, and the reader/writer runs as the plain relayout it
+    always was."""
     if side == "src":
         src_layout, dst_layout = layout, L.MN
     elif side == "dst":
         src_layout, dst_layout = L.MN, layout
     else:
         raise ValueError(f"side must be 'src' or 'dst', got {side!r}")
-    return _specializing(chain, src_layout, dst_layout, d_buf, interpret,
-                         lambda aval: None)
-
-
-def maybe_compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *,
-                       side: str, d_buf: int = 9) -> Optional[Callable]:
-    """Side-fusion policy for remote movements: fuse a non-empty, fully
-    emit-capable chain whose payload stays a plain array (pytree payloads
-    like QTensor/CTensor split the stream across the collective), else None.
-    Sides with no plugins don't count as fallbacks — there is no chain to
-    fuse, and the reader/writer runs as the plain relayout it always was."""
     chain = tuple(chain)
     if not chain:
         return None
@@ -397,7 +400,8 @@ def maybe_compile_side(layout: L.Layout, chain: Sequence[P.Plugin], *,
             if p.pytree_payload:
                 reason = f"pytree-payload:{p.name}"
                 break
-    _record(reason is None, reason or "")
     if reason is not None:
+        _record(False, reason)
         return None
-    return compile_side(layout, chain, side=side, d_buf=d_buf)
+    return _specializing(chain, src_layout, dst_layout, d_buf,
+                         lambda aval: None, strict=False)

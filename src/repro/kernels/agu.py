@@ -147,8 +147,7 @@ class AGUPlan:
         m, n = self.logical_shape
         return (n, m) if self.transpose else (m, n)
 
-    def run(self, x: jnp.ndarray, *,
-            interpret: Optional[bool] = None) -> jnp.ndarray:
+    def run(self, x: jnp.ndarray) -> jnp.ndarray:
         if self.kind == "identity":
             return x
         m, n = self.logical_shape
@@ -175,7 +174,7 @@ class AGUPlan:
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(
                 self.dst_layout.physical_shape(self.out_logical), x.dtype),
-            interpret=interpret_mode(interpret),
+            interpret=interpret_mode(),
         )(x)
 
 

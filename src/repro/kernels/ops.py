@@ -10,14 +10,29 @@ watches it).
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax.numpy as jnp
 
 from repro.core import layouts as L
+from repro.core import plugins as P
 from . import agu
 from .fused_rmsnorm_relayout import rmsnorm_relayout
 from .quant import quantize_tiled
 
-__all__ = ["relayout", "rmsnorm_relayout", "quantize_tiled"]
+__all__ = ["chain_transpose", "relayout", "rmsnorm_relayout",
+           "quantize_tiled"]
+
+
+def chain_transpose(chain: Sequence[P.Plugin]) -> Optional[bool]:
+    """A plugin chain read as an AGU relayout: its ``transpose`` flag when
+    the chain is empty or one ``Transpose``, else None (the AGU has no
+    other plugin stage)."""
+    if not chain:
+        return False
+    if len(chain) == 1 and isinstance(chain[0], P.Transpose):
+        return True
+    return None
 
 
 def relayout(x: jnp.ndarray, *, src_layout: L.Layout, dst_layout: L.Layout,

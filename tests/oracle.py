@@ -15,8 +15,10 @@ are plain-numpy twins of ``QTensor`` / ``CTensor`` with the same fields.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Sequence, Tuple
 
+import ml_dtypes
 import numpy as np
 
 from repro.core import layouts as L
@@ -167,6 +169,69 @@ def oracle_transfer(x, desc: XDMADescriptor) -> Any:
     return _write(y, desc.dst.layout)
 
 
+# -- excess precision: the answers XLA may give for half-precision sums -------
+# XLA may drop an f32 -> bf16 -> f32 convert pair (the CPU backend does), so a
+# half-precision stage's output can reach the next stage unrounded.  Elsewhere
+# that is within the half-precision tolerance; a sum, though, adds up the
+# per-term rounding (and can cancel to near zero).  These answers model the
+# elision exactly instead of widening the tolerance: every subset of the
+# half-precision stages may skip its rounding.
+_HALF = (np.dtype(np.float16), np.dtype(ml_dtypes.bfloat16))
+
+
+def _chain_rounding_at(plugins, x: np.ndarray, keep) -> Any:
+    """apply_chain with the value carried in float32, rounded to its
+    half-precision dtype only after the stages ``keep`` marks."""
+    dtype = x.dtype
+    v = x.astype(np.float32)
+    for p, k in zip(plugins, keep):
+        dtype = np.dtype(p.out_dtype(dtype))
+        if not isinstance(p, P.Cast):
+            v = apply_plugin(p, v)
+        if k and dtype in _HALF and isinstance(v, np.ndarray):
+            v = v.astype(dtype).astype(np.float32)
+    return v.astype(dtype)
+
+
+def oracle_answers(x, desc: XDMADescriptor) -> list:
+    """Every answer ``xdma.transfer(x, desc)`` may give: the strict
+    :func:`oracle_transfer`, and for a sum over half-precision values, the
+    same chain with each subset of its half-precision roundings elided."""
+    want = oracle_transfer(x, desc)
+    chain = tuple(desc.pre) + tuple(desc.post)
+    if (not any(isinstance(p, P.ReduceStage) and p.op == "sum" for p in chain)
+            or any(isinstance(p, (P.Quantize, P.Dequantize)) for p in chain)):
+        return [want]
+    dtype, sites = np.asarray(x).dtype, []
+    for i, p in enumerate(chain[:-1]):     # the stored output always rounds
+        dtype = np.dtype(p.out_dtype(dtype))
+        if dtype in _HALF:
+            sites.append(i)
+    logical = to_logical(np.asarray(x), desc.src.layout)
+    answers = [want]
+    for kept in itertools.product((True, False), repeat=len(sites)):
+        if all(kept):
+            continue                       # the strict answer, already in
+        keep = [True] * len(chain)
+        for i, k in zip(sites, kept):
+            keep[i] = k
+        y = _chain_rounding_at(chain, logical, keep)
+        answers.append(_write(y, desc.dst.layout))
+    return answers
+
+
+def assert_matches_any(got: Any, wants: Sequence[Any], **kw) -> None:
+    """got ~= at least one of ``wants`` (see :func:`assert_matches`); on a
+    miss, reports the mismatch against the strict answer ``wants[0]``."""
+    for want in wants[1:]:
+        try:
+            assert_matches(got, want, **kw)
+            return
+        except AssertionError:
+            pass
+    assert_matches(got, wants[0], **kw)
+
+
 def assert_matches(got: Any, want: Any, *, rtol: float = 2e-5,
                    atol: float = 1e-5, context: str = "") -> None:
     """got (jax, QTensor/CTensor/array) ~= want (oracle).  Tolerances are for
@@ -208,11 +273,9 @@ def chain_tolerance(*descs, logical=None) -> dict:
     ``ReduceStage("sum")`` widens the absolute tolerance by what a sum of
     its rows can differ by: np sums pairwise and XLA in its own order, and
     the first-order bound on a sum's rounding error is linear in its number
-    of terms (the float32 noise above is calibrated at 128 terms).  A sum
-    of half-precision terms can also differ by the terms' own rounding:
-    XLA may compute the products feeding it in float32 and never round them
-    to bf16 (the CPU backend drops such convert pairs), up to half a bf16
-    ulp per term, so rows x max|term| x 2^-9."""
+    of terms (the tolerances above are calibrated at 128 terms).  The
+    rounding XLA may skip in a sum of half-precision terms is not a
+    tolerance: :func:`oracle_answers` models it."""
     chain = [p for d in descs for p in tuple(d.pre) + tuple(d.post)]
     if any(isinstance(p, P.Dequantize) for p in chain):
         return dict(rtol=5e-2, atol=0.25)
@@ -223,11 +286,6 @@ def chain_tolerance(*descs, logical=None) -> dict:
         y = np.asarray(logical)
         for p in chain:
             if isinstance(p, P.ReduceStage) and p.op == "sum":
-                rows = y.shape[-2]
-                if y.dtype.itemsize < 4:
-                    bound = rows * float(np.abs(y.astype(np.float32)).max())
-                    tol["atol"] = max(tol["atol"], bound * 2.0 ** -9)
-                else:
-                    tol["atol"] *= max(1.0, rows / 128)
+                tol["atol"] *= max(1.0, y.shape[-2] / 128)
             y = apply_plugin(p, y)
     return tol

@@ -203,11 +203,11 @@ def run_transfer(x, desc):
 def check_against_oracle(case: DescCase):
     x, desc = case.build()
     got = run_transfer(x, desc)
-    want = O.oracle_transfer(x, desc)
+    wants = O.oracle_answers(x, desc)
     logical_in = desc.src.layout.logical_shape(x.shape)
     logical = O.to_logical(np.asarray(x), desc.src.layout)
-    O.assert_matches(got, want, context=repr(case),
-                     **O.chain_tolerance(desc, logical=logical))
+    O.assert_matches_any(got, wants, context=repr(case),
+                         **O.chain_tolerance(desc, logical=logical))
     # compile-time contracts agree with what executed
     out_logical = desc.out_logical_shape(logical_in)
     values = got.values if isinstance(got, (P.QTensor, P.CTensor)) else got

@@ -194,15 +194,35 @@ def test_plugin_compiler_cfg_stats():
     ct = xdma.transfer(x, C.describe("MN", "MN", C.Compress(block_rows=8)))
     assert isinstance(ct, C.CTensor)
     assert "payload-output:compress_blocksparse" in PC.cfg_stats()["reasons"]
-    # a whole-block kernel larger than VMEM takes the XLA composition, per
-    # input shape, with the same bits
+
+
+def test_plugin_compiler_tallies_each_lowering_once():
+    """fused + fallback counts each lowering once: a refused chain at its
+    CFG phase, a fusible one where its kernel is chosen, so a block too
+    large for VMEM takes the XLA composition (same bits) as a fallback and
+    never also counts as fused.  A forced kernel (backend='compiled')
+    raises there instead of running XLA."""
+    from repro.core import plugin_compiler as PC
+    from repro.core import xdma
+    xdma.clear_cache()
+    PC.clear_stats()
+    x = rand((64, 256), 9)
     big = rand((1024, 1024), 3)
-    perm = np.arange(1023, -1, -1)
-    desc = C.describe("MN", "MN", C.GatherScatter(indices=perm))
-    got = xdma.transfer(big, desc)
-    assert PC.cfg_stats()["reasons"].get("vmem-block") == 1
-    want = xdma.transfer(big, dataclasses.replace(desc, backend="fused"))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    gather = C.describe("MN", "MN",
+                        C.GatherScatter(indices=np.arange(1023, -1, -1)))
+    lowerings = [(x, C.describe("MN", "MNM8N128", C.Scale(1.25))),   # streamed
+                 (x, C.describe("MN", "MN", C.Transpose())),          # AGU
+                 (x, C.describe("MN", "MNM32N128", C.Quantize())),    # refused
+                 (big, gather)]                                       # vmem-block
+    outs = [xdma.transfer(arr, desc) for arr, desc in lowerings]
+    stats = PC.cfg_stats()
+    assert stats["fused"] + stats["fallback"] == len(lowerings)
+    assert (stats["fused"], stats["fallback"]) == (2, 2)
+    assert set(stats["reasons"]) == {"no-emit:quantize_int8", "vmem-block"}
+    want = xdma.transfer(big, dataclasses.replace(gather, backend="fused"))
+    np.testing.assert_array_equal(np.asarray(outs[-1]), np.asarray(want))
+    with pytest.raises(ValueError, match="vmem-block"):
+        xdma.transfer(big, dataclasses.replace(gather, backend="compiled"))
 
 
 def test_plugin_compiler_squeezes_unit_leading_dims():

@@ -1,9 +1,9 @@
 """Real-width compiles of the main-path kernels for a described v5e chip.
 
-Nothing runs: each kernel is lowered with ``interpret=False`` against one
-chip of a described ``v5e:2x2`` topology and compiled by the TPU compiler
-installed with jax, which refuses what the chip would refuse (VMEM overflow,
-unaligned slices, layouts Mosaic cannot lower).  Each compiled program must
+Nothing runs: each kernel is lowered under ``forced_interpret(False)``
+against one chip of a described ``v5e:2x2`` topology and compiled by the
+TPU compiler installed with jax, which refuses what the chip would refuse
+(VMEM overflow, unaligned slices, layouts Mosaic cannot lower).  Each compiled program must
 hold a Mosaic kernel (``tpu_custom_call``), so no kernel silently fell back
 to XLA.  Sizes are the serving path's: one full-context K matrix (S=4096,
 d_kv=8x128) and one (32, 1024) KV page.
@@ -19,7 +19,7 @@ from repro.core import (MN, RMSNormPlugin, Transpose, describe,
                         layout_for_dtype, plugin_compiler)
 from repro.core import plugins as P
 from repro.core.descriptor import page_descriptor
-from repro.kernels import agu
+from repro.kernels import agu, forced_interpret
 
 KV_MATRIX = (4096, 1024)
 PAGE = (32, 1024)
@@ -55,7 +55,9 @@ def no_compile_cache():
 
 def _mosaic_compile(fn, shape, dtype, sharding):
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    text = jax.jit(fn).lower(x).compile().as_text()
+    with forced_interpret(False):
+        lowered = jax.jit(fn).lower(x)
+    text = lowered.compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
 
 
@@ -64,14 +66,13 @@ def test_agu_tile_kernel(dtype, one_chip, no_compile_cache):
     tiled = layout_for_dtype(dtype)
     plan, reason = agu.plan_relayout(MN, tiled, KV_MATRIX)
     assert plan is not None and plan.kind == "kernel", reason
-    _mosaic_compile(lambda x: plan.run(x, interpret=False), KV_MATRIX, dtype,
-                    one_chip)
+    _mosaic_compile(plan.run, KV_MATRIX, dtype, one_chip)
 
 
 def test_kv_store_rmsnorm_tile(one_chip, no_compile_cache):
     """The serving path's prefill store: RMSNorm on the stream + tile."""
     desc = describe(MN, layout_for_dtype(jnp.bfloat16), RMSNormPlugin())
-    _mosaic_compile(plugin_compiler.compile_local(desc, interpret=False),
+    _mosaic_compile(plugin_compiler.compile_local(desc),
                     KV_MATRIX, jnp.bfloat16, one_chip)
 
 
@@ -79,7 +80,7 @@ def test_kv_load_tiled_transposed(one_chip, no_compile_cache):
     """The serving path's load: tiled K back as K^T in MN."""
     tiled = layout_for_dtype(jnp.bfloat16)
     desc = describe(tiled, MN, Transpose())
-    _mosaic_compile(plugin_compiler.compile_local(desc, interpret=False),
+    _mosaic_compile(plugin_compiler.compile_local(desc),
                     tiled.physical_shape(KV_MATRIX), jnp.bfloat16, one_chip)
 
 
@@ -94,7 +95,7 @@ def test_page_compress_evict_restore(direction, dtype, one_chip,
                            wire_compress_rows=8)
     assert plugin_compiler.can_fuse(desc) == (True, "fusible")
     shape = desc.src.layout.physical_shape(PAGE)
-    _mosaic_compile(plugin_compiler.compile_local(desc, interpret=False),
+    _mosaic_compile(plugin_compiler.compile_local(desc),
                     shape, dtype, one_chip)
 
 
