@@ -19,16 +19,18 @@ load natively):
   ``scheduler`` / ``compute``), so all three movement chokepoints are
   visible as categories.
 * :func:`telemetry_events` — a :class:`~repro.runtime.telemetry.Telemetry`
-  session's spans (engine step phases on the simulated clock, chokepoint
-  spans on the host clock), one row per track.
+  session's spans (engine steps and their phases, scheduler flushes, pool
+  commits and the chokepoints, all on the host clock), one row per track.
 * :func:`export` / :func:`to_json` — wrap events as
   ``{"traceEvents": [...]}`` and write/return the JSON.
 * :func:`validate_events` — the schema gate tests and CI run on every
   exported file.
 
-Timestamps are microseconds (the trace-event contract).  Simulated-clock
-sources (sim replays, engine phases) share one timebase, so a serving
-replay and its engine-phase spans line up in Perfetto.
+Timestamps are microseconds (the trace-event contract).  Sim replays run on
+the simulated clock and telemetry sessions on the host clock: the two are
+separate timebases (separate ``pid`` rows), not aligned.  The host spans
+line up with device ops in a ``jax.profiler`` trace instead, where
+:func:`repro.runtime.telemetry.span` annotates them.
 """
 from __future__ import annotations
 

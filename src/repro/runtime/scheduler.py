@@ -64,14 +64,17 @@ __all__ = ["XDMAFuture", "MulticastFuture", "DistributedScheduler"]
 # CSR-style counter banks (DESIGN.md §11): per-link byte/burst/stall tallies,
 # per-resource queue-occupancy high-water marks, and the ring plane's
 # doorbell / credit / fairness counters.  Always counting — the increments
-# are dict adds, same cost class as the old ad-hoc stats — while span timing
-# stays gated on an active telemetry session.
+# are dict adds, same cost class as the old ad-hoc stats — while per-task
+# span timing stays gated on an active telemetry session.
 _LINKS = _tm.bank("links")
 _QUEUES = _tm.bank("queues")
 _RINGS = _tm.bank("rings")
 # The multicast plane (DESIGN.md §14): trees built, hops/forks posted, and
 # the wire bytes shared hops avoid moving vs N private unicast copies.
 _MCAST = _tm.bank("multicast")
+# XDMA tasks that ran inside a fused round program (``batched_tasks``); all
+# dispatched tasks are the ``links`` bank's ``tasks:<resource>`` counters.
+_SCHED = _tm.bank("sched")
 
 # Batched-round programs, shared by every scheduler instance: keyed by the
 # round's descriptor identities (same scheme as the CFG cache), so a fresh
@@ -109,6 +112,14 @@ def _nbytes(value: Any) -> int:
             import numpy as np
             total += int(size) * int(np.dtype(dtype).itemsize)
     return total
+
+
+def _round_program(fns: Tuple[Callable, ...]) -> Callable:
+    """One jitted program running a round's batched lowerings (the profile
+    names it ``jit_sched_round``)."""
+    def sched_round(xs):
+        return tuple(f(x) for f, x in zip(fns, xs))
+    return jax.jit(sched_round)
 
 
 class XDMAFuture:
@@ -593,9 +604,8 @@ class DistributedScheduler:
             key = tuple(ready[i].desc.cache_key() for i in batch)
             fused = _ROUND_CACHE.get(key)
             if fused is None:
-                fns = tuple(_api._lowered(ready[i].desc) for i in batch)
-                fused = jax.jit(lambda xs, _fns=fns:
-                                tuple(f(x) for f, x in zip(_fns, xs)))
+                fused = _round_program(
+                    tuple(_api._lowered(ready[i].desc) for i in batch))
                 _ROUND_CACHE[key] = fused
                 while len(_ROUND_CACHE) > _ROUND_CACHE_CAPACITY:
                     _ROUND_CACHE.popitem(last=False)
@@ -604,6 +614,7 @@ class DistributedScheduler:
             outs = fused(tuple(inputs[i] for i in batch))
             for i, out in zip(batch, outs):
                 ready[i].value = out
+            _SCHED.inc("batched_tasks", len(batch))
         else:
             batch = []
         fused_ids = set(batch)
@@ -708,9 +719,11 @@ class DistributedScheduler:
         return True
 
     def flush(self) -> None:
-        """Drain every ring (runs scheduling rounds until idle)."""
-        while self.step():
-            pass
+        """Drain every ring (runs scheduling rounds until idle), inside a
+        ``sched.flush`` span."""
+        with _tm.span("sched.flush", "scheduler", tasks=self._pending):
+            while self.step():
+                pass
 
     @property
     def pending(self) -> int:

@@ -15,14 +15,17 @@ module is that CSR file for the whole reproduction (DESIGN.md §11):
   accounting, ``PagedKVPool.stats`` — are now thin views over these banks.
   The ring plane (DESIGN.md §12) adds a ``rings`` bank: doorbell posts,
   ring-full events, credits-in-flight high-water, per-tenant dispatches.
-* :class:`Telemetry` — a *session*: span-based timing (host clock via
-  context managers, simulated clock via :meth:`Telemetry.add_span`) and
-  value histograms (serving TTFT/TBT).  Sessions follow the same ambient
-  discipline as :func:`repro.runtime.trace.capture`: :func:`session`
-  installs one, the chokepoints (``xdma.transfer``, ``XDMAQueue.run``,
-  ``DistributedScheduler.submit``/``submit_compute``) and the serving
-  engines' per-step phases guard on a single ``is None`` check — with no
-  session open, spans cost nothing and :func:`snapshot` returns ``{}``.
+* :class:`Telemetry` — a *session*: host-clock spans (context managers)
+  and value histograms (serving TTFT/TBT).  Sessions follow the same
+  ambient discipline as :func:`repro.runtime.trace.capture`: :func:`session`
+  installs one, and the per-task chokepoints (``xdma.transfer``,
+  ``XDMAQueue.run``, ``DistributedScheduler.submit``/``submit_compute``)
+  guard on a single ``is None`` check — with no session open they cost
+  nothing and :func:`snapshot` returns ``{}``.
+* :func:`span` — the layer spans (the serving engine's step phases, the
+  scheduler's flush, the page pool's commit) have a second sink: while
+  ``jax.profiler`` traces, each is also a ``TraceAnnotation`` of the same
+  name, on the profile's clock beside the device ops.
 * :func:`snapshot` — the one read port: every counter bank, every span,
   every histogram, plus the legacy surfaces re-exported verbatim, in one
   JSON-ready dict.  :mod:`repro.runtime.chrometrace` turns the spans (and
@@ -30,8 +33,9 @@ module is that CSR file for the whole reproduction (DESIGN.md §11):
   trace-event JSON loadable in Perfetto.
 
 This module is intentionally a *leaf*: it imports only the standard library
-at module scope, so the low-level modules it instruments (``core/api``,
-``kernels/agu``, ``core/plugin_compiler``) can import it without cycles.
+at module scope (the profiler hook resolves on first use), so the low-level
+modules it instruments (``core/api``, ``kernels/agu``,
+``core/plugin_compiler``) can import it without cycles.
 """
 from __future__ import annotations
 
@@ -146,7 +150,8 @@ def reset(domain: Optional[str] = None) -> None:
 class SpanEvent:
     """One timed region.  ``track`` groups spans into timeline rows
     (``transfer`` / ``queue`` / ``scheduler`` for the chokepoints,
-    ``engine`` for serving-step phases); ``depth``/``parent`` encode the
+    ``engine`` for serving-step phases, ``scheduler`` and ``pool`` for the
+    flush and commit); ``depth``/``parent`` encode the
     nesting observed at record time (host-clock spans nest by the Python
     ``with`` stack — under jit/shard_map that is trace-time nesting, once
     per compilation, exactly like :func:`repro.runtime.trace.capture`)."""
@@ -173,9 +178,7 @@ class SpanEvent:
 class Telemetry:
     """One telemetry session: spans and value histograms.
 
-    ``clock`` supplies host-side span timestamps (default
-    ``time.perf_counter``); simulated-clock spans bypass it through
-    :meth:`add_span` with explicit times.
+    ``clock`` supplies the span timestamps (default ``time.perf_counter``).
     """
 
     def __init__(self, name: str = "telemetry",
@@ -205,15 +208,6 @@ class Telemetry:
         finally:
             self._stack.pop()
             ev.end_s = self.clock()
-
-    def add_span(self, name: str, start_s: float, end_s: float, *,
-                 track: str = "sim", **args: Any) -> SpanEvent:
-        """Record a span with explicit timestamps (the serving engines'
-        simulated-clock step phases)."""
-        ev = SpanEvent(name=name, track=track, start_s=float(start_s),
-                       end_s=float(end_s), args=dict(args))
-        self.spans.append(ev)
-        return ev
 
     def spans_on(self, track: str) -> List[SpanEvent]:
         return [s for s in self.spans if s.track == track]
@@ -278,10 +272,38 @@ def session(tel: Optional[Telemetry] = None, *, name: str = "telemetry",
         _ACTIVE = prev
 
 
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, once resolved
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use (this module
+    keeps to the standard library at module scope)."""
+    global _ANNOTATION
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION = TraceAnnotation
+    return TraceAnnotation
+
+
+@contextlib.contextmanager
+def _both(tel: Telemetry, ann, name: str, track: str,
+          args: Dict[str, Any]) -> Iterator[SpanEvent]:
+    with ann(name, **args), tel.span(name, track=track, **args) as ev:
+        yield ev
+
+
 def span(name: str, track: str = "host", **args: Any):
-    """Module-level span hook: a real span inside an open session, a shared
-    no-op context otherwise (one ``is None`` check, nothing allocated)."""
+    """Module-level span hook with two sinks: an open session records a
+    :class:`SpanEvent`, and a running ``jax.profiler`` trace gets a
+    ``TraceAnnotation`` named ``name`` whose stats are ``args``, on the
+    same clock as the device ops.  With neither it returns a shared no-op
+    context (one ``is None`` check and one ``is_enabled()`` call, nothing
+    allocated)."""
     a = _ACTIVE
+    ann = _ANNOTATION or _annotation()
+    if ann.is_enabled():
+        if a is None:
+            return ann(name, **args)
+        return _both(a, ann, name, track, args)
     if a is None:
         return _NULL
     return a.span(name, track=track, **args)

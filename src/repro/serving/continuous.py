@@ -24,6 +24,15 @@ position.  Each serving step:
    dirty pages scatter back;
 6. the simulated clock advances by the step's scheduler makespan.
 
+Each step is one ``engine.step`` span on the host clock, with its phases
+(``engine.admit``, ``prefill``, ``preempt``, ``gather``, ``compose``,
+``decode``, ``scatter``, ``defrag``) nested inside it: recorded by an open
+telemetry session and, while ``jax.profiler`` traces, annotated on the
+profile beside the device ops (:func:`repro.runtime.telemetry.span`).
+``ServeReport``'s latency fields read the simulated clock's token stamps;
+with a session open, its ``ttft_s``/``tbt_s`` histograms take host-clock
+stamps.
+
 ``StaticBatchEngine`` is the baseline: same pool, same kernels, but gang
 admission only (a new batch forms only when the previous one fully drains,
 and finished members keep occupying batch rows and page traffic until the
@@ -151,6 +160,20 @@ def _from_canonical(meta: _LeafMeta, mat: jnp.ndarray,
     return mat.reshape(nb_shape)
 
 
+def _engine_programs(cfg, mesh):
+    """The engine's jitted prefill and decode, built from named functions so
+    that a profile names their programs ``jit_engine_prefill`` and
+    ``jit_engine_decode``."""
+    def engine_prefill(params, batch, cache):
+        return lm.prefill(cfg, params, batch, cache, mesh=mesh)
+
+    def engine_decode(params, tokens, cache):
+        return lm.decode_step(cfg, params, tokens, cache, mesh=mesh)
+
+    return (jax.jit(engine_prefill),
+            jax.jit(engine_decode, donate_argnums=(2,)))
+
+
 # ---------------------------------------------------------------------------
 # request state + report
 # ---------------------------------------------------------------------------
@@ -162,9 +185,14 @@ class _ReqState:
     generated: List[int] = dataclasses.field(default_factory=list)
     pages: Dict[int, List[int]] = dataclasses.field(default_factory=dict)
     finish_s: float = -1.0
-    # simulated-clock stamp of every generated token (SLO metrics: TTFT is
-    # token_times[0] - arrival, TBT the successive differences)
+    # simulated-clock stamp of every generated token (ServeReport's SLO
+    # fields: TTFT is token_times[0] - arrival, TBT the successive gaps)
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # with a telemetry session open, the same on the session's host clock:
+    # when the engine first saw the request arrived, and when its latest
+    # token reached the host (the session's ttft_s/tbt_s histograms)
+    host_arrival_s: float = -1.0
+    host_last_s: float = -1.0
 
     @property
     def done_tokens(self) -> bool:
@@ -241,10 +269,7 @@ class ContinuousBatchingEngine:
         self.pool = pool if pool is not None else PagedKVPool(
             capacity_pages if capacity_pages is not None else 64, page_rows)
         self.metas, self._template = _leaf_metas(cfg, max_len, cache_dtype)
-        self._prefill = jax.jit(functools.partial(lm.prefill, cfg, mesh=mesh))
-        self._decode = jax.jit(functools.partial(lm.decode_step, cfg,
-                                                 mesh=mesh),
-                               donate_argnums=(2,))
+        self._prefill, self._decode = _engine_programs(cfg, mesh)
         self._n_params = sum(
             int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)
             if getattr(l, "ndim", 0) >= 1)
@@ -419,17 +444,15 @@ class ContinuousBatchingEngine:
     def _gang_done(self, active) -> bool:     # continuous: free immediately
         return False
 
-    def _mark(self, tel, sched, t0, cursor, name):
-        """Close one engine phase on the simulated clock: the span runs from
-        ``cursor`` to ``t0 + makespan-so-far`` (everything submitted up to
-        this point).  Callers flush before marking, so ``makespan()`` is the
-        scheduler's O(1) incremental value from its completion queue — a
-        telemetry-on serve step no longer pays a full replay per phase."""
-        now = t0 + sched.makespan()
-        if now > cursor:
-            tel.add_span(f"engine.{name}", cursor, now, track="engine",
-                         step=self.steps, engine=self.name)
-        return max(cursor, now)
+    @staticmethod
+    def _stamp(st: _ReqState, now: float, tel) -> None:
+        """Host-clock stamp of a token that has just reached the host, into
+        the open session's ``ttft_s``/``tbt_s`` histograms."""
+        if st.host_last_s < 0:
+            tel.record_value("ttft_s", now - st.host_arrival_s)
+        else:
+            tel.record_value("tbt_s", now - st.host_last_s)
+        st.host_last_s = now
 
     # -- the serving loop ----------------------------------------------------
     def serve(self, requests: Sequence[Request], *,
@@ -442,6 +465,8 @@ class ContinuousBatchingEngine:
         queue = [_ReqState(r) for r in
                  sorted(requests, key=lambda r: (r.arrival_s, r.rid))]
         states = {st.req.rid: st for st in queue}
+        arrivals = list(queue)                     # arrival order
+        n_arrived = 0
         active: List[_ReqState] = []
         preempted: List[_ReqState] = []
         clock = 0.0
@@ -453,54 +478,94 @@ class ContinuousBatchingEngine:
             if not active and not preempted and queue \
                     and queue[0].req.arrival_s > clock:
                 clock = queue[0].req.arrival_s     # idle: jump to next arrival
-            sched = self._new_scheduler()
-            self.last_scheduler = sched
-            self.pool.bind(sched)
-            _SERVING.inc("steps")
-            _SERVING.record_max("queue_depth_hw", len(queue))
-            cursor = clock                         # engine-phase span cursor
+            if tel is not None:
+                # a request's host-clock arrival: the first step that sees it
+                now = tel.clock()
+                while n_arrived < len(arrivals) \
+                        and arrivals[n_arrived].req.arrival_s <= clock:
+                    arrivals[n_arrived].host_arrival_s = now
+                    n_arrived += 1
+            with _tm.span("engine.step", "engine", step=self.steps,
+                          engine=self.name):
+                clock += self._step(clock, queue, active, preempted, tel)
+                self.steps += 1
 
+                # stamp every token generated this step at the post-step
+                # clock (prefill's first token and decode's next token both
+                # land when the step's movement drains — the simulated-clock
+                # base of ServeReport's SLO fields)
+                for st in states.values():
+                    while len(st.token_times) < len(st.generated):
+                        st.token_times.append(clock)
+
+                # completions: continuous frees a request the step it drains;
+                # a static gang keeps its finished rows resident (finish time
+                # still stamped at their own last token) until everyone drains
+                holds = self._gang_holds(active)
+                for st in [s for s in active if s.done_tokens]:
+                    if holds:
+                        if st.finish_s < 0:
+                            st.finish_s = clock
+                    else:
+                        self._finish(st, active, clock)
+
+        return self._report(states, clock)
+
+    def _step(self, clock: float, queue: List[_ReqState],
+              active: List[_ReqState], preempted: List[_ReqState],
+              tel) -> float:
+        """One serving step, each phase in an ``engine.<phase>`` span on the
+        host clock.  Returns the step's scheduler makespan, the simulated
+        clock's advance (0 when nothing is active after admission)."""
+        sched = self._new_scheduler()
+        self.last_scheduler = sched
+        self.pool.bind(sched)
+        _SERVING.inc("steps")
+        _SERVING.record_max("queue_depth_hw", len(queue))
+
+        with _tm.span("engine.admit", "engine"):
             restored, admitted = self._admit(active, preempted, queue, clock)
             if restored:
                 sched.flush()
                 self.pool.commit()                 # restored pages land now
-            if tel is not None:
-                cursor = self._mark(tel, sched, clock, cursor, "admission")
 
-            # prefill new admissions, grouped by prompt length so one jitted
-            # program covers each group (and a gang of equal prompts runs the
-            # exact fixed-batch prefill program)
-            by_len: Dict[int, List[_ReqState]] = {}
-            for st in admitted:
-                by_len.setdefault(st.req.prompt_len, []).append(st)
-            for plen, group in sorted(by_len.items()):
-                toks = jnp.asarray(np.stack([st.req.tokens for st in group]),
-                                   jnp.int32)
-                cache0 = lm.init_cache(self.cfg, len(group), self.max_len,
-                                       self.cache_dtype)
-                logits, cache = self._prefill(self.params,
-                                              {"tokens": toks}, cache0)
-                cost = 2.0 * self._n_params * len(group) * plen / HW_FLOPS
-                cfut = sched.submit_compute(lambda *a: None, cost_s=cost,
-                                            label=f"compute:prefill:{plen}")
-                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-                for i, st in enumerate(group):
-                    st.pos = plen
-                    st.generated.append(int(nxt[i]))
-                for i, (st, c1) in enumerate(
-                        zip(group, self._split_cache(cache, len(group)))):
-                    self._scatter(st, c1, deps=(cfut,), label="store")
-            if admitted:
+        # prefill new admissions, grouped by prompt length so one jitted
+        # program covers each group (and a gang of equal prompts runs the
+        # exact fixed-batch prefill program)
+        if admitted:
+            with _tm.span("engine.prefill", "engine", requests=len(admitted)):
+                by_len: Dict[int, List[_ReqState]] = {}
+                for st in admitted:
+                    by_len.setdefault(st.req.prompt_len, []).append(st)
+                for plen, group in sorted(by_len.items()):
+                    toks = jnp.asarray(
+                        np.stack([st.req.tokens for st in group]), jnp.int32)
+                    cache0 = lm.init_cache(self.cfg, len(group), self.max_len,
+                                           self.cache_dtype)
+                    logits, cache = self._prefill(self.params,
+                                                  {"tokens": toks}, cache0)
+                    cost = 2.0 * self._n_params * len(group) * plen / HW_FLOPS
+                    cfut = sched.submit_compute(
+                        lambda *a: None, cost_s=cost,
+                        label=f"compute:prefill:{plen}")
+                    nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+                    now = tel.clock() if tel is not None else 0.0
+                    for i, st in enumerate(group):
+                        st.pos = plen
+                        st.generated.append(int(nxt[i]))
+                        if tel is not None:
+                            self._stamp(st, now, tel)
+                    for st, c1 in zip(group,
+                                      self._split_cache(cache, len(group))):
+                        self._scatter(st, c1, deps=(cfut,), label="store")
                 sched.flush()
                 self.pool.commit()
-            if tel is not None:
-                cursor = self._mark(tel, sched, clock, cursor, "prefill")
 
-            if not active:
-                self.steps += 1
-                continue
+        if not active:
+            return 0.0
 
-            # memory pressure: will the next decode's page growth fit?
+        # memory pressure: will the next decode's page growth fit?
+        with _tm.span("engine.preempt", "engine"):
             decoding = [st for st in active if not st.done_tokens
                         or self._gang_member(st)]
             growth = sum(self._growth(st.pos) for st in decoding)
@@ -520,15 +585,16 @@ class ContinuousBatchingEngine:
                 decoding = [st for st in active if not st.done_tokens
                             or self._gang_member(st)]
                 growth = sum(self._growth(st.pos) for st in decoding)
-            if tel is not None:
-                cursor = self._mark(tel, sched, clock, cursor, "preempt")
 
-            # gather -> compose -> decode -> scatter dirty pages
+        # gather -> compose -> decode -> scatter dirty pages
+        with _tm.span("engine.gather", "engine"):
             gathered = [self._gather(st) for st in active]
             sched.flush()
-            if tel is not None:
-                cursor = self._mark(tel, sched, clock, cursor, "gather")
+        with _tm.span("engine.compose", "engine"):
             cache = self._compose_cache(active, gathered)
+        # the program call through the argmax read-back: the step's one
+        # host-device sync
+        with _tm.span("engine.decode", "engine", batch=len(active)):
             toks = jnp.asarray([[st.generated[-1]] for st in active],
                                jnp.int32)
             logits, cache = self._decode(self.params, toks, cache)
@@ -536,58 +602,27 @@ class ContinuousBatchingEngine:
             cost = 2.0 * self._n_params * len(active) / HW_FLOPS
             cfut = sched.submit_compute(lambda *a: None, *gfuts, cost_s=cost,
                                         label="compute:decode")
-            if tel is not None:
-                sched.flush()              # decode cost lands before the mark
-                cursor = self._mark(tel, sched, clock, cursor, "decode")
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            now = tel.clock() if tel is not None else 0.0
+        with _tm.span("engine.scatter", "engine"):
             for i, (st, c1) in enumerate(
                     zip(active, self._split_cache(cache, len(active)))):
                 written = st.pos                   # decode wrote this slot
                 st.pos = min(st.pos + 1, self.max_len)
                 if not st.done_tokens:
                     st.generated.append(int(nxt[i]))
+                    if tel is not None:
+                        self._stamp(st, now, tel)
                 self._scatter(st, c1, deps=(cfut,), dirty_from=written,
                               label="decode")
             sched.flush()
             self.pool.commit()
-            if tel is not None:
-                cursor = self._mark(tel, sched, clock, cursor, "scatter")
-            if self.auto_defrag and self.pool.fragmentation():
+        if self.auto_defrag and self.pool.fragmentation():
+            with _tm.span("engine.defrag", "engine"):
                 self.pool.defrag()
                 sched.flush()
                 self.pool.commit()
-                if tel is not None:
-                    cursor = self._mark(tel, sched, clock, cursor, "defrag")
-
-            clock += sched.makespan()
-            self.steps += 1
-
-            # stamp every token generated this step at the post-step clock
-            # (prefill's first token and decode's next token both land when
-            # the step's movement drains — the simulated-clock SLO base)
-            for st in states.values():
-                while len(st.token_times) < len(st.generated):
-                    st.token_times.append(clock)
-                    if tel is not None:
-                        if len(st.token_times) == 1:
-                            tel.record_value(
-                                "ttft_s", clock - st.req.arrival_s)
-                        else:
-                            tel.record_value(
-                                "tbt_s", clock - st.token_times[-2])
-
-            # completions: continuous frees a request the step it drains;
-            # a static gang keeps its finished rows resident (finish time
-            # still stamped at their own last token) until everyone drains
-            holds = self._gang_holds(active)
-            for st in [s for s in active if s.done_tokens]:
-                if holds:
-                    if st.finish_s < 0:
-                        st.finish_s = clock
-                else:
-                    self._finish(st, active, clock)
-
-        return self._report(states, clock)
+        return sched.makespan()
 
     def _gang_member(self, st: _ReqState) -> bool:
         return False                               # continuous: no gangs

@@ -313,18 +313,20 @@ class PagedKVPool:
     def commit(self) -> None:
         """After the bound scheduler flushed, land pending movements: store
         results become the at-rest buffers, evicted pages release their
-        slots, restored pages take their reserved ones."""
-        for p, fut, loc, slot in self._pending:
-            p.data = fut.result()
-            if p.location == "dev" and loc == "host":
-                self._free_slots.append(p.slot)
-                self._free_slots.sort()
-            p.location = loc
-            if loc == "dev" and slot >= 0:
-                p.slot = slot
-            elif loc == "host":
-                p.slot = -1
-        self._pending.clear()
+        slots, restored pages take their reserved ones (a ``pool.commit``
+        span)."""
+        with _tm.span("pool.commit", "pool", pages=len(self._pending)):
+            for p, fut, loc, slot in self._pending:
+                p.data = fut.result()
+                if p.location == "dev" and loc == "host":
+                    self._free_slots.append(p.slot)
+                    self._free_slots.sort()
+                p.location = loc
+                if loc == "dev" and slot >= 0:
+                    p.slot = slot
+                elif loc == "host":
+                    p.slot = -1
+            self._pending.clear()
 
     def summary(self) -> str:
         return (f"PagedKVPool({self.name!r}, {self.used_pages}/{self.capacity}"

@@ -16,6 +16,7 @@ Acceptance properties (ISSUE 7):
     snapshot reports.
 """
 import dataclasses
+import glob
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,24 @@ from repro.runtime import (DistributedScheduler, Topology, capture,
 def rand(shape, seed=0, dtype=jnp.float32):
     return jnp.asarray(np.random.default_rng(seed).standard_normal(shape),
                        dtype)
+
+
+def profiled(fn, trace_dir):
+    """Run ``fn`` under ``jax.profiler`` (Python tracer off); returns its
+    result and the ``/host:CPU`` events as (name, start_ns, end_ns, stats)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))[-1]
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+    return out, host
 
 
 @pytest.fixture(scope="module")
@@ -80,7 +99,31 @@ def test_snapshot_empty_and_span_noop_without_session():
     telemetry.record_value("ttft_s", 1.0)       # no-op, must not raise
 
 
-def test_results_bit_identical_with_and_without_session():
+def test_span_hook_sinks_profiler_and_session(tmp_path):
+    """One span API, two sinks: the profiler gets a TraceAnnotation (name
+    exact, args as stats), the session a SpanEvent, and with both open
+    each gets its own."""
+    assert telemetry.span("anything") is telemetry._NULL
+
+    def spans():
+        with telemetry.span("test.phase", "test", step=3, engine="cb"):
+            pass
+    _, host = profiled(spans, tmp_path / "profiler")
+    got = [(n, st) for n, _, _, st in host if n == "test.phase"]
+    assert got == [("test.phase", {"step": 3, "engine": "cb"})]
+
+    with telemetry.session(name="s") as tel:
+        spans()
+    assert [(s.name, s.track, s.args) for s in tel.spans] == [
+        ("test.phase", "test", {"step": 3, "engine": "cb"})]
+
+    with telemetry.session(name="both") as tel:
+        _, host = profiled(spans, tmp_path / "both")
+    assert [s.name for s in tel.spans] == ["test.phase"]
+    assert [n for n, *_ in host if n == "test.phase"] == ["test.phase"]
+
+
+def test_results_bit_identical_with_and_without_session(model, tmp_path):
     x = rand((64, 128))
     desc = C.describe("MN", "MNM8N128")
     off = xdma.transfer(x, desc)
@@ -91,6 +134,26 @@ def test_results_bit_identical_with_and_without_session():
     assert [s.name for s in tel.spans] == ["xdma.transfer"]
     events = chrometrace.telemetry_events(telemetry.Telemetry("empty"))
     assert all(e["ph"] == "M" for e in events)   # no spans -> no X events
+
+    # the engine serves the same tokens with a session or the profiler on
+    from repro.serving import ContinuousBatchingEngine, uniform_stream
+
+    cfg, params = model
+    reqs = uniform_stream(cfg, 3, 1e-5, prompt_len=8, max_new=3, seed=0)
+
+    def serve():
+        eng = ContinuousBatchingEngine(cfg, params, max_len=24, max_batch=2,
+                                       cache_dtype=jnp.float32,
+                                       capacity_pages=48)
+        return eng.serve(reqs).tokens
+    off = serve()
+    with telemetry.session(name="on"):
+        on_session = serve()
+    on_profiler, _ = profiled(serve, tmp_path)
+    for got in (on_session, on_profiler):
+        assert got.keys() == off.keys()
+        for rid in off:
+            np.testing.assert_array_equal(got[rid], off[rid])
 
 
 # -- counter/ledger/report reconciliation ------------------------------------
@@ -324,6 +387,11 @@ def test_rings_bank_counts_doorbells_and_snapshot_surfaces_them():
 
 
 # -- snapshot + serving SLO --------------------------------------------------
+ENGINE_PHASES = {"engine.admit", "engine.prefill", "engine.preempt",
+                 "engine.gather", "engine.compose", "engine.decode",
+                 "engine.scatter", "engine.defrag"}
+
+
 def _serve_under_session(model, n_requests=3):
     from repro.serving import ContinuousBatchingEngine, uniform_stream
 
@@ -359,10 +427,65 @@ def test_snapshot_subsumes_surfaces_and_slo_histograms(model):
         == rep.total_tokens - rep.n_requests
     assert rep.ttft_p99_s >= rep.ttft_p50_s >= 0.0
     assert rep.tbt_p99_s >= rep.tbt_p50_s >= 0.0
-    # engine phase spans on the simulated clock
+    # engine phase spans on the host clock, nested in their steps
     phases = {s.name for s in tel.spans_on("engine")}
     assert {"engine.prefill", "engine.gather", "engine.decode",
             "engine.scatter"} <= phases
+    steps = [i for i, s in enumerate(tel.spans) if s.name == "engine.step"]
+    assert len(steps) == eng.steps
+    assert all(tel.spans[s.parent].name == "engine.step"
+               for s in tel.spans if s.name in ENGINE_PHASES)
+
+
+def test_engine_phases_on_the_profilers_clock(model, tmp_path):
+    """Under the profiler every step is one ``engine.step`` with its phases
+    nested inside, every ``sched.flush`` lies inside a phase, and the
+    engine's programs carry their names (``jit_<name>``)."""
+    from repro.serving import ContinuousBatchingEngine, uniform_stream
+
+    cfg, params = model
+    reqs = uniform_stream(cfg, 3, 1e-5, prompt_len=8, max_new=3, seed=0)
+    eng = ContinuousBatchingEngine(cfg, params, max_len=24, max_batch=2,
+                                   cache_dtype=jnp.float32, capacity_pages=48)
+    eng.serve(reqs)                              # compile outside the trace
+    _, host = profiled(lambda: eng.serve(reqs), tmp_path)
+    steps = [(s, e) for n, s, e, _ in host if n == "engine.step"]
+    phases = [(n, s, e) for n, s, e, _ in host if n in ENGINE_PHASES]
+    flushes = [(s, e) for n, s, e, _ in host if n == "sched.flush"]
+    assert len(steps) == eng.steps > 0
+    assert {"engine.admit", "engine.prefill", "engine.gather",
+            "engine.compose", "engine.decode",
+            "engine.scatter"} <= {n for n, _, _ in phases}
+    steps.sort()
+    assert all(e <= s for (_, e), (s, _) in zip(steps, steps[1:]))
+    inside = lambda s, e, spans: sum(a <= s and e <= b for a, b in spans)
+    assert all(inside(s, e, steps) == 1 for _, s, e in phases)
+    assert flushes and all(inside(s, e, [(a, b) for _, a, b in phases]) == 1
+                           for s, e in flushes)
+    # a program jitted from ``f`` runs as PjitFunction(f), module jit_f
+    programs = {n[len("PjitFunction("):-1] for n, *_ in host
+                if n.startswith("PjitFunction(")}
+    assert {"engine_prefill", "engine_decode"} <= programs
+    assert "_unknown" not in programs
+
+
+def test_sched_bank_counts_batched_tasks():
+    """``batched_tasks`` counts the XDMA tasks of fused rounds; all XDMA
+    tasks dispatched are the ``links`` bank's ``tasks:<resource>``."""
+    telemetry.reset("sched")
+    links = telemetry.bank("links")
+    tasks = lambda: sum(links.with_prefix("tasks:").values())
+    before = tasks()
+    sched = DistributedScheduler(Topology.parallel(3))
+    x = rand((64, 128))
+    for link in ("link0", "link1", "link2"):     # one round, fused
+        sched.submit(x, C.describe("MN", "MNM8N128"), link=link)
+    sched.submit(x, C.describe("MN", "MN"), link="link0")   # a round alone
+    sched.submit_compute(lambda: None, cost_s=1e-6)
+    sched.flush()
+    sched.flush()                                # nothing left: no round
+    assert telemetry.bank("sched").as_dict() == {"batched_tasks": 3}
+    assert tasks() - before == 4
 
 
 def test_chrome_trace_exports_chokepoints_and_engine_phases(model, tmp_path):
