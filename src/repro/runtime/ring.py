@@ -23,8 +23,7 @@ Pure Python, no JAX.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 __all__ = ["DEFAULT_RING_DEPTH", "WouldBlock", "DescriptorRing", "Completion"]
 
@@ -118,8 +117,7 @@ class DescriptorRing:
                 f", head={self._head}, tail={self._tail})")
 
 
-@dataclasses.dataclass(frozen=True)
-class Completion:
+class Completion(NamedTuple):
     """One completion-queue entry: the engine retired a ring head.
 
     ``start_s``/``end_s`` are the simulated span the dispatch occupies —

@@ -20,14 +20,23 @@ of unbounded FIFOs.
 * ``submit_compute(fn, ...)`` enqueues interleaved compute (expert FFN, host
   preprocessing) on a named compute engine so transfer/compute overlap is
   visible to the simulator.
-* ``flush()`` drains the rings in *scheduling rounds*: each round takes one
-  ready ring head per resource — round-robin over that resource's tenant
-  rings, which is what keeps a starved tenant near its fair share under
-  adversarial load — and dispatches them together.  Local concrete-array
-  tasks are fused into one batched XLA program per round (cached by the
-  tuple of descriptor identities), everything else dispatches through
-  exactly the same cached lowering ``xdma.transfer`` uses, so results are
-  bit-identical to a serial replay of the same descriptors.
+* ``step()`` runs one *scheduling round*: it takes one ready ring head per
+  resource — round-robin over that resource's tenant rings, which is what
+  keeps a starved tenant near its fair share under adversarial load — and
+  dispatches them together.  Local concrete-array tasks run as batched XLA
+  programs, one per group of equal (descriptor, shape, dtype), in
+  power-of-two chunks of at most ``_CHUNK_MAX`` tasks, each one cached
+  ``jit_sched_round`` program; everything else dispatches alone.  Every
+  task runs exactly the cached lowering ``xdma.transfer`` uses, so results
+  are bit-identical to a serial replay of the same descriptors.
+* ``flush()`` drains the rings in the same rounds, but its rounds are
+  *logical*: it plans them (ring pops, round numbers, stall accounting)
+  exactly as repeated ``step()`` would, and carries the batched tasks
+  across rounds, so a drain of hundreds of page ops runs as a few
+  programs.  The batch runs early before a task that reads one of its
+  values and before any task that cannot batch.  Completions, makespan,
+  the replay and every counter match a drain by repeated ``step()``; only
+  the number of programs launched differs.
 
 Every dispatch retires its ring head into a completion queue
 (``scheduler.completions``) carrying the simulated span — which resolves
@@ -48,6 +57,7 @@ import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
 
 from repro.core import api as _api
 from repro.core import autotune as _autotune
@@ -72,17 +82,24 @@ _RINGS = _tm.bank("rings")
 # The multicast plane (DESIGN.md §14): trees built, hops/forks posted, and
 # the wire bytes shared hops avoid moving vs N private unicast copies.
 _MCAST = _tm.bank("multicast")
-# XDMA tasks that ran inside a fused round program (``batched_tasks``); all
-# dispatched tasks are the ``links`` bank's ``tasks:<resource>`` counters.
+# XDMA tasks that ran inside a fused round program (``batched_tasks``) and
+# XDMA programs launched (``programs``: fused rounds or chunks, and single
+# dispatches); all dispatched tasks are the ``links`` bank's
+# ``tasks:<resource>`` counters.
 _SCHED = _tm.bank("sched")
 
 # Batched-round programs, shared by every scheduler instance: keyed by the
-# round's descriptor identities (same scheme as the CFG cache), so a fresh
-# scheduler per step replays compiled rounds instead of retracing them.
+# group's descriptor identity (same scheme as the CFG cache) and the chunk's
+# width, so a fresh scheduler per step replays compiled rounds instead of
+# retracing them.
 # Bounded LRU for the same reason the CFG cache is: id-keyed descriptor
 # churn must not pin programs (and captured weight arrays) forever.
 _ROUND_CACHE: "collections.OrderedDict[Any, Callable]" = collections.OrderedDict()
 _ROUND_CACHE_CAPACITY = 256
+# Widest fused program the scheduler launches for a group of equal descriptors:
+# a group runs as chunks of power-of-two widths up to this, so a descriptor
+# compiles at most log2(_CHUNK_MAX) + 1 round programs per input geometry.
+_CHUNK_MAX = 64
 # Round programs inline CFG-cache lowerings, so xdma.clear_cache() must drop
 # them too — a stale round program would bypass the cleared cache.
 _api._AUX_CACHES.append(_ROUND_CACHE)
@@ -109,7 +126,6 @@ def _nbytes(value: Any) -> int:
         size = getattr(leaf, "size", None)
         dtype = getattr(leaf, "dtype", None)
         if size is not None and dtype is not None:
-            import numpy as np
             total += int(size) * int(np.dtype(dtype).itemsize)
     return total
 
@@ -120,6 +136,29 @@ def _round_program(fns: Tuple[Callable, ...]) -> Callable:
     def sched_round(xs):
         return tuple(f(x) for f, x in zip(fns, xs))
     return jax.jit(sched_round)
+
+
+def _chunk_widths(n: int) -> List[int]:
+    """The fused-program widths a group of ``n`` equal tasks runs as: full
+    ``_CHUNK_MAX`` chunks, then the remainder's binary digits, largest first
+    (200 -> 64, 64, 64, 8)."""
+    widths = [_CHUNK_MAX] * (n // _CHUNK_MAX)
+    rest = n % _CHUNK_MAX
+    while rest:
+        w = 1 << (rest.bit_length() - 1)
+        widths.append(w)
+        rest -= w
+    return widths
+
+
+def _signature(x: Any) -> Any:
+    """The input geometry one group shares: an array's shape, dtype and
+    placement, a payload pytree's structure and leaf geometries."""
+    if isinstance(x, jax.Array):
+        return x.shape, x.dtype, x.sharding
+    leaves, tree = jax.tree_util.tree_flatten(x)
+    return tree, tuple((getattr(l, "shape", None), getattr(l, "dtype", None))
+                       for l in leaves)
 
 
 class XDMAFuture:
@@ -252,6 +291,11 @@ class DistributedScheduler:
         self._sim_end: Dict[int, float] = {}         # task id -> simulated end
         self._sim_free: Dict[str, float] = {}        # resource -> busy-until
         self._makespan_inc = 0.0         # incremental makespan (== replay)
+        # per-geometry memos of a dispatch's simulated duration and its
+        # ``links`` counter increments (pure functions of the key)
+        self._link_time: Dict[Tuple, float] = {}
+        self._link_incs: Dict[Tuple, Tuple] = {}
+        self._desc_facts: Dict[int, Tuple] = {}   # see _facts
         self._pending = 0
         self._next_id = 0
         self._next_link = 0              # round-robin routing cursor
@@ -577,98 +621,177 @@ class DistributedScheduler:
                 _LINKS.inc(f"stall_rounds:{res}")
         return ready
 
-    @staticmethod
-    def _batchable(t: _Task, x: Any) -> bool:
-        # Local concrete tasks batch whatever their lowering: the XLA
-        # composition and the plugin-compiler's fused Pallas programs
-        # (backend auto/compiled) both jit into the round program — only the
-        # raw pallas relayout backend keeps its own dispatch path.
+    def _facts(self, desc: XDMADescriptor) -> Tuple[bool, bool]:
+        """``(has_auto, local)`` of a descriptor, worked out once per
+        descriptor object (the memo holds the object, so its id stays
+        unique while the scheduler lives).  ``local``: the task may run
+        inside a round program whatever its lowering — the XLA composition
+        and the plugin-compiler's fused Pallas programs (backend
+        auto/compiled) both jit into it; only the raw pallas relayout
+        backend keeps its own dispatch path."""
+        hit = self._desc_facts.get(id(desc))
+        if hit is None:
+            hit = self._desc_facts[id(desc)] = (
+                desc, desc.has_auto,
+                desc.movement == "local" and desc.backend != "pallas")
+        return hit[1], hit[2]
+
+    def _batchable(self, t: _Task, x: Any) -> bool:
         return (t.kind == "xdma" and t.desc is not None
-                and t.desc.movement == "local" and t.desc.backend != "pallas"
+                and self._facts(t.desc)[1]
                 and not isinstance(x, jax.core.Tracer))
 
-    def _dispatch_round(self, ready: List[_Task]) -> None:
-        inputs = [self._resolve(t.inputs[0]) if t.inputs else None
-                  for t in ready]
-        for i, t in enumerate(ready):
-            # auto descriptors fed by futures resolve here, against the
-            # producer's now-known output and the task's routed link
-            if t.kind == "xdma" and t.desc is not None and t.desc.has_auto:
-                t.desc = self._resolve_auto(t.desc, inputs[i], t.resource)
-        batch = [i for i, t in enumerate(ready)
-                 if self._batchable(t, inputs[i])]
-        if len(batch) > 1:
-            # One batched XLA program for the round: the cached per-descriptor
-            # lowerings are inlined into a single jitted tuple program, cached
-            # by the round's descriptor identities.
-            key = tuple(ready[i].desc.cache_key() for i in batch)
-            fused = _ROUND_CACHE.get(key)
-            if fused is None:
-                fused = _round_program(
-                    tuple(_api._lowered(ready[i].desc) for i in batch))
-                _ROUND_CACHE[key] = fused
-                while len(_ROUND_CACHE) > _ROUND_CACHE_CAPACITY:
-                    _ROUND_CACHE.popitem(last=False)
-            else:
-                _ROUND_CACHE.move_to_end(key)
-            outs = fused(tuple(inputs[i] for i in batch))
-            for i, out in zip(batch, outs):
-                ready[i].value = out
-            _SCHED.inc("batched_tasks", len(batch))
-        else:
-            batch = []
-        fused_ids = set(batch)
-        for i, t in enumerate(ready):
-            if i not in fused_ids:
-                if t.kind == "xdma":
-                    t.value = _api._lowered(t.desc)(inputs[i])
+    def _input(self, t: _Task) -> Any:
+        """The task's resolved input.  An ``auto`` descriptor fed by a future
+        resolves here, against the producer's now-known output and the
+        task's routed link."""
+        x = self._resolve(t.inputs[0]) if t.inputs else None
+        if t.kind == "xdma" and t.desc is not None and self._facts(t.desc)[0]:
+            t.desc = self._resolve_auto(t.desc, x, t.resource)
+        return x
+
+    def _run_single(self, t: _Task, x: Any) -> Any:
+        """Dispatch one task on its own: an XDMA task through the cached
+        lowering ``xdma.transfer`` uses, a compute task's function."""
+        if t.kind == "xdma":
+            _SCHED.inc("programs")
+            return _api._lowered(t.desc)(x)
+        return t.fn(*(self._resolve(a) for a in t.inputs))
+
+    def _plan_round(self, ready: List[_Task],
+                    batch: Dict[int, Tuple[_Task, Any]]) -> None:
+        """Dispatch one round's tasks in ready order.  Local concrete-array
+        tasks retire at once and wait in ``batch`` (task id -> task, input);
+        the batch runs first (:meth:`_run_deferred`) whenever a task reads
+        one of its values or a task that cannot batch is picked (compute,
+        ``pallas`` backend, remote, tracers), so every task is accounted in
+        plan order."""
+        for t in ready:
+            src = t.inputs[0] if t.inputs else None
+            if batch and (t.kind != "xdma" or (isinstance(src, XDMAFuture)
+                                               and src.task_id in batch)):
+                self._run_deferred(batch)
+            x = self._input(t)
+            if self._batchable(t, x):
+                self._retire(t)
+                batch[t.id] = (t, x)
+                continue
+            self._run_deferred(batch)
+            value = self._run_single(t, x)
+            self._retire(t)
+            self._finish(t, x, value)
+        self._rounds += 1
+
+    def _run_deferred(self, batch: Dict[int, Tuple[_Task, Any]]) -> None:
+        """Execute the deferred tasks (plan order) as grouped programs, then
+        empty ``batch``.
+
+        Tasks group by equal (descriptor, input geometry); each group runs as
+        :func:`_chunk_widths` chunks, one cached ``jit_sched_round`` program
+        each, and its lowering, payload bytes and burst are worked out once.
+        The per-task accounting then runs in plan order."""
+        if not batch:
+            return
+        tasks = list(batch.values())
+        batch.clear()
+        by_desc: Dict[Any, List[int]] = {}
+        for i, (t, x) in enumerate(tasks):
+            by_desc.setdefault((id(t.desc), _signature(x)), []).append(i)
+        groups: Dict[Any, List[int]] = {}
+        for (_, sig), idxs in by_desc.items():  # equal descriptor objects merge
+            groups.setdefault((tasks[idxs[0]][0].desc.cache_key(), sig),
+                              []).extend(idxs)
+        values: List[Any] = [None] * len(tasks)
+        sizes: List[Any] = [None] * len(tasks)
+        programs = 0
+        for (key, _), idxs in groups.items():
+            desc, x0 = tasks[idxs[0]][0].desc, tasks[idxs[0]][1]
+            lo = 0
+            for w in _chunk_widths(len(idxs)):
+                part = idxs[lo:lo + w]
+                lo += w
+                ckey = (key, w)
+                fused = _ROUND_CACHE.get(ckey)
+                if fused is None:
+                    fused = _round_program((_api._lowered(desc),) * w)
+                    _ROUND_CACHE[ckey] = fused
+                    while len(_ROUND_CACHE) > _ROUND_CACHE_CAPACITY:
+                        _ROUND_CACHE.popitem(last=False)
                 else:
-                    t.value = t.fn(*(self._resolve(a) for a in t.inputs))
-            if t.nbytes is None:
-                t.nbytes = (_nbytes(inputs[i]) + _nbytes(t.value)
-                            if t.kind == "xdma" else 0)
-            if t.burst_bytes is None and t.kind == "xdma":
-                t.burst_bytes = _burst_bytes(t.desc, inputs[i])
-            if t.event is not None and t.kind == "xdma":
+                    _ROUND_CACHE.move_to_end(ckey)
+                for i, out in zip(part, fused(tuple(tasks[i][1]
+                                                    for i in part))):
+                    values[i] = out
+                programs += 1
+            group_sizes = (_nbytes(x0) + _nbytes(values[idxs[0]]),
+                           _burst_bytes(desc, x0))
+            for i in idxs:
+                sizes[i] = group_sizes
+        _SCHED.inc("batched_tasks", len(tasks))
+        _SCHED.inc("programs", programs)
+        for (t, x), value, size in zip(tasks, values, sizes):
+            self._finish(t, x, value, size)
+
+    def _retire(self, t: _Task) -> None:
+        """Pop a dispatched task's ring head (returning its credit) and mark
+        it done in the current round."""
+        popped = self._rings[t.resource][t.tenant].pop()
+        assert popped == t.id, (popped, t.id)
+        self._dispatched.setdefault(t.resource, []).append(t.id)
+        self._pending -= 1
+        t.done = True
+        t.round = self._rounds
+
+    def _finish(self, t: _Task, x: Any, value: Any,
+                sizes: Optional[Tuple[int, Optional[int]]] = None) -> None:
+        """Land a retired task's value: payload bytes and burst (``sizes``
+        when its group worked them out), the ledger row, the per-link
+        counters and the completion."""
+        t.value = value
+        if t.kind == "xdma":
+            if t.nbytes is None or t.burst_bytes is None:
+                nbytes, burst = sizes or (_nbytes(x) + _nbytes(value),
+                                          _burst_bytes(t.desc, x))
+                if t.nbytes is None:
+                    t.nbytes = nbytes
+                if t.burst_bytes is None:
+                    t.burst_bytes = burst
+            if t.event is not None:
                 # finalize the ledger row with the measured payload, and
                 # register this task's output provenance with the trace that
                 # OWNS the event (not whatever capture happens to be ambient
                 # at flush time — a lazily-drained scheduler must not leak
                 # its event ids into an unrelated trace)
                 t.trace.finalize(t.event, nbytes=t.nbytes,
-                                 burst_bytes=t.burst_bytes,
-                                 value=inputs[i])
-                t.trace.register_value(t.event, t.value)
-            if t.kind == "xdma":
-                self._count_dispatch(t)
-            t.done = True
-            t.round = self._rounds
-            self._complete(t)
-        self._rounds += 1
+                                 burst_bytes=t.burst_bytes, value=x)
+                t.trace.register_value(t.event, value)
+            self._count_dispatch(t)
+        elif t.nbytes is None:
+            t.nbytes = 0
+        self._complete(t)
 
     def _complete(self, t: _Task) -> None:
-        """Retire a dispatched task's ring head: return its credit, push a
-        completion-queue entry, and advance the incremental makespan.
+        """Push a finished task's completion-queue entry and advance the
+        incremental makespan.
 
         The span arithmetic mirrors ``simulator.simulate`` operation for
         operation (same dep-max, same ``transfer_time`` call, same doorbell
         add), and per-resource completion order IS the replay's queue order,
         so ``_makespan_inc`` is bit-equal to ``report().makespan`` whenever
         the rings are drained."""
-        popped = self._rings[t.resource][t.tenant].pop()
-        assert popped == t.id, (popped, t.id)
-        self._dispatched.setdefault(t.resource, []).append(t.id)
-        self._pending -= 1
         ready = max((self._sim_end[d] for d in t.deps), default=0.0)
         start = max(ready, self._sim_free.get(t.resource, 0.0))
         if t.resource in self.topology:
-            link = self.topology.link(t.resource)
-            dur = link.transfer_time(
-                int(t.nbytes or 0), t.burst_bytes,
-                issue_overhead=None,
-                pipeline_depth=(t.desc.d_buf if t.desc is not None else 1))
-            if t.csr_writes:
-                dur += t.csr_writes * link.csr_write_cost
+            key = (t.resource, int(t.nbytes or 0), t.burst_bytes,
+                   t.desc.d_buf if t.desc is not None else 1, t.csr_writes)
+            dur = self._link_time.get(key)
+            if dur is None:              # equal page ops price alike
+                link = self.topology.link(t.resource)
+                dur = link.transfer_time(key[1], key[2], issue_overhead=None,
+                                         pipeline_depth=key[3])
+                if t.csr_writes:
+                    dur += t.csr_writes * link.csr_write_cost
+                self._link_time[key] = dur
         else:
             dur = max(0.0, float(t.cost_s))
         stop = start + dur
@@ -676,9 +799,8 @@ class DistributedScheduler:
         self._sim_free[t.resource] = stop
         if stop > self._makespan_inc:
             self._makespan_inc = stop
-        self.completions.append(Completion(
-            task_id=t.id, resource=t.resource, tenant=t.tenant,
-            round=self._rounds, start_s=start, end_s=stop))
+        self.completions.append(Completion(t.id, t.resource, t.tenant,
+                                           t.round, start, stop))
         _RINGS.inc(f"tenant_dispatch:{t.tenant or 'default'}")
 
     def _count_dispatch(self, t: _Task) -> None:
@@ -686,44 +808,72 @@ class DistributedScheduler:
         (exactly the ledger's ``per_link_bytes`` contribution), wire bytes,
         generated bursts, and the amortized address-issue overhead the cost
         model charges (``bursts * burst_overhead / d_buf``)."""
-        res = t.resource
         nbytes = int(t.nbytes or 0)
-        _LINKS.inc(f"tasks:{res}")
-        _LINKS.inc(f"bytes:{res}", nbytes)
         wire = (int(t.event.wire_nbytes)
                 if t.event is not None and t.event.wire_nbytes is not None
                 else nbytes)
-        _LINKS.inc(f"wire_bytes:{res}", wire)
-        if t.burst_bytes and nbytes > 0:
-            n_bursts = -(-nbytes // int(t.burst_bytes))
+        key = (t.resource, nbytes, wire, t.burst_bytes,
+               t.desc.d_buf if t.desc is not None else 1)
+        incs = self._link_incs.get(key)
+        if incs is None:                 # equal page ops count alike
+            incs = self._link_incs[key] = self._dispatch_incs(*key)
+        for name, n in incs:
+            _LINKS.inc(name, n)
+
+    def _dispatch_incs(self, res: str, nbytes: int, wire: int,
+                       burst: Optional[int], depth: int):
+        """The ``links`` increments of one dispatch of this geometry."""
+        if burst and nbytes > 0:
+            n_bursts = -(-nbytes // int(burst))
         else:
             n_bursts = 1 if nbytes > 0 else 0
-        _LINKS.inc(f"bursts:{res}", n_bursts)
-        if res in self.topology and n_bursts and t.burst_bytes:
+        incs = [(f"tasks:{res}", 1), (f"bytes:{res}", nbytes),
+                (f"wire_bytes:{res}", wire), (f"bursts:{res}", n_bursts)]
+        if res in self.topology and n_bursts and burst:
             link = self.topology.link(res)
-            depth = t.desc.d_buf if t.desc is not None else 1
-            _LINKS.inc(f"issue_ns:{res}",
-                       int(round(n_bursts * link.burst_overhead * 1e9
-                                 / max(1, int(depth)))))
+            incs.append((f"issue_ns:{res}",
+                         int(round(n_bursts * link.burst_overhead * 1e9
+                                   / max(1, int(depth))))))
+        return tuple(incs)
 
     def step(self) -> bool:
-        """Run one scheduling round; returns False when nothing is pending."""
+        """Run one scheduling round, executed at once; returns False when
+        nothing is pending."""
         ready = self._ready_heads()
         if not ready:
-            if self.pending:
-                raise ValueError(
-                    f"scheduler deadlocked with {self.pending} pending tasks "
-                    "(dependency cycle across rings?)")
+            self._check_drained()
             return False
-        self._dispatch_round(ready)
+        batch: Dict[int, Tuple[_Task, Any]] = {}
+        self._plan_round(ready, batch)
+        self._run_deferred(batch)
         return True
 
     def flush(self) -> None:
-        """Drain every ring (runs scheduling rounds until idle), inside a
-        ``sched.flush`` span."""
+        """Drain every ring, inside a ``sched.flush`` span.
+
+        The rounds are logical: planning runs them exactly as repeated
+        :meth:`step` would (tenant arbitration, stall counts, ring pops,
+        ``t.round``), but the deferred batch carries across rounds, so the
+        local concrete-array tasks of many rounds run as a few programs
+        grouped by descriptor (:meth:`_plan_round`).  Values, completions,
+        makespan and every counter but the ``sched`` bank's equal a drain by
+        ``step()``."""
         with _tm.span("sched.flush", "scheduler", tasks=self._pending):
-            while self.step():
-                pass
+            batch: Dict[int, Tuple[_Task, Any]] = {}
+            while True:
+                ready = self._ready_heads()
+                if not ready:
+                    break
+                self._plan_round(ready, batch)
+            self._run_deferred(batch)
+            self._check_drained()
+
+    def _check_drained(self) -> None:
+        """With no ready ring head, every task must have dispatched."""
+        if self.pending:
+            raise ValueError(
+                f"scheduler deadlocked with {self.pending} pending tasks "
+                "(dependency cycle across rings?)")
 
     @property
     def pending(self) -> int:

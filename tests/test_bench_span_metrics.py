@@ -9,7 +9,7 @@ from bench.trace import reduce as R
 
 READERS = ("engine.step_ms", "engine.page_host_share",
            "sched.flush_us_per_task", "sched.batched_share",
-           "device_idle.serve.unnamed")
+           "device_idle.serve.unnamed", "sched.tasks_per_program")
 WINDOW = (500, 10_000)
 OPS = [(1400, 1450, "fusion"), (2100, 2900, "fusion"), (6100, 7900, "fusion"),
        (8560, 8600, "fusion")]
@@ -47,7 +47,7 @@ def run_of(host, banks):
 def test_span_and_counter_readers_read_exact_values():
     run = run_of(HOST, {"links": {"tasks:link0": 3, "tasks:link1": 1,
                                   "bytes:link0": 4096},
-                        "sched": {"batched_tasks": 3}})
+                        "sched": {"batched_tasks": 3, "programs": 2}})
     got = {name: reader(name).read(run) for name in READERS}
     # steps starting in the window: 3000 ns and 4000 ns
     assert got["engine.step_ms"] == pytest.approx(3500 / 1e6)
@@ -56,6 +56,7 @@ def test_span_and_counter_readers_read_exact_values():
     # flushes in the window: 200 + 600 + 200 ns over 4 tasks
     assert got["sched.flush_us_per_task"] == pytest.approx(1000 / 1e3 / 4)
     assert got["sched.batched_share"] == pytest.approx(75.0)
+    assert got["sched.tasks_per_program"] == pytest.approx(4 / 2)
     # idle gaps: 500-1400 (mid 950, no span), 1450-2100 (compose),
     # 2900-6100 (mid 4500: only a runtime event), 7900-8560 (scatter),
     # 8600-10000 (mid 9300: pool.commit)
@@ -114,3 +115,26 @@ def test_phase_tool_splits_idle_time_by_engine_phase():
     assert got["busy_in_phase_s"] == pytest.approx(
         {"engine.admit": 2e-7, "engine.gather": 3e-7, "engine.decode": 5e-7,
          "engine.compose": 0.0})
+
+
+def test_tasks_per_program_is_silent_on_a_program_without_the_counter():
+    """A ``sched`` bank without ``programs`` (the parent of the grouped
+    flush) reads as absent, not as zero or infinity."""
+    read = reader("sched.tasks_per_program").read
+    banks = {"links": {"tasks:d2h0": 6, "tasks:d2h1": 6},
+             "sched": {"batched_tasks": 12}}
+    assert read(run_of(HOST, banks)) is None
+    banks["sched"]["programs"] = 3
+    assert read(run_of(HOST, banks)) == pytest.approx(4.0)
+
+
+def test_serve_cell_flushes_run_grouped():
+    """A traced serving run at the small CPU size launches fewer XDMA
+    programs than it dispatches tasks: its flushes run grouped."""
+    import time
+
+    from bench import smoke
+    r = harness.execute(smoke.SERVE_CELL, 2**33 + 11, 0.5, 1,
+                        t_start=time.perf_counter(), **smoke.kw(smoke.SERVE_CELL))
+    assert r["correct"]
+    assert r["metrics"]["sched.tasks_per_program"]["value"] > 1

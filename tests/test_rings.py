@@ -334,3 +334,159 @@ def test_depth2_rings_survive_forced_preemption_with_token_parity():
         for r in reqs:
             np.testing.assert_array_equal(got.tokens[r.rid],
                                           ref.tokens[r.rid])
+
+
+# -- grouped flush: logical rounds, grouped programs ------------------------------
+def _page():
+    """One at-rest (16, 128) bfloat16 page."""
+    from repro.core.descriptor import page_descriptor
+    return xdma.transfer(rand((16, 128), 3, jnp.bfloat16),
+                         page_descriptor(16, 128, "bfloat16"))
+
+
+def _page_loads(sched, xs):
+    """200 loads of one page descriptor over the two d2h links."""
+    from repro.core.descriptor import page_descriptor
+    desc = page_descriptor(16, 128, "bfloat16", direction="load")
+    return [sched.submit(xs[0], desc, link=("d2h0", "d2h1")[i % 2])
+            for i in range(200)]
+
+
+_MIXED = (C.describe("MN", "MNM8N128"), C.describe("MN", "MN"),
+          C.describe("MN", "MNM8N128", C.RMSNormPlugin()),
+          C.describe("MN", "MN", C.Scale(2.0)))
+
+
+def _mixed(sched, xs):
+    """Mixed descriptors and shapes, spread over every link."""
+    links = ("h2d0", "d2h0", "h2d1", "d2h1")
+    return [sched.submit(xs[(i // 4) % 2], _MIXED[i % 4],
+                         link=links[(i * 7) % 4]) for i in range(40)]
+
+
+def _cross_link_deps(sched, xs):
+    """Chains whose data and ordering-only dependencies cross links."""
+    store = C.describe("MN", "MNM8N128")
+    load = C.describe("MNM8N128", "MN", C.Transpose())
+    futs = []
+    for i in range(6):
+        f1 = sched.submit(xs[0], store, link=("h2d0", "h2d1")[i % 2])
+        f2 = sched.submit(f1, load, link=("d2h1", "d2h0")[i % 2])
+        f3 = sched.submit(xs[0], store, link="h2d1", deps=(f2,))
+        futs += [f1, f2, f3, sched.submit(xs[0], store, link="h2d0")]
+    return futs
+
+
+def _compute_mid(sched, xs):
+    """A compute task in the middle of a drain, fed by deferred tasks and
+    feeding later ones."""
+    desc = C.describe("MN", "MN", C.Scale(2.0))
+    first = [sched.submit(xs[0], desc, link=("h2d0", "h2d1")[i % 2])
+             for i in range(8)]
+    cf = sched.submit_compute(lambda a, b: a + b, first[0], first[5],
+                              cost_s=3e-6)
+    after = [sched.submit(cf, desc, link="h2d0")]
+    after += [sched.submit(xs[0], desc, link="d2h0", deps=(cf,))
+              for _ in range(5)]
+    return first + [cf] + after
+
+
+def _tenants(sched, xs):
+    """Several tenants on one link, round-robin arbitrated."""
+    desc = C.describe("MN", "MNM8N128")
+    futs = [sched.submit(xs[0], desc, link="h2d0", tenant="bulk")
+            for _ in range(12)]
+    futs += [sched.submit(xs[1], desc, link="h2d0", tenant=tn)
+             for tn in ("a", "b") for _ in range(3)]
+    futs += [sched.submit(xs[0], desc, link="h2d1", tenant="a")
+             for _ in range(4)]
+    return futs
+
+
+def _drain(schedule, drain, under_jit):
+    """Run ``schedule`` on a fresh scheduler drained by ``drain``; returns
+    the values, completions, makespans, rounds and counter deltas."""
+    import jax
+
+    telemetry.reset("rings")                 # it keeps high-water marks
+    links, rings = telemetry.bank("links"), telemetry.bank("rings")
+    before = links.as_dict()
+    box = []
+
+    def body(*xs):
+        sched = DistributedScheduler(Topology.host_device(2))
+        futs = schedule(sched, xs)
+        drain(sched)
+        box.append((sched, futs))
+        return [f.result() for f in futs]
+
+    xs = (rand((64, 128), 1), rand((128, 256), 2).astype(jnp.bfloat16))
+    if schedule is _page_loads:
+        xs = (_page(),)
+    values = jax.jit(body)(*xs) if under_jit else body(*xs)
+    sched, futs = box[0]
+    return {
+        "values": [np.asarray(v) for v in values],
+        "completions": [(c.task_id, c.resource, c.tenant, c.round, c.start_s,
+                         c.end_s) for c in sched.completions],
+        "makespan": (sched.makespan(), sched.report().makespan),
+        "rounds": [sched._tasks[f.task_id].round for f in futs],
+        "links": {k: v - before.get(k, 0) for k, v in links.as_dict().items()
+                  if v != before.get(k, 0)},
+        "rings": rings.as_dict(),
+    }
+
+
+def _steps(sched):
+    while sched.step():
+        pass
+
+
+@pytest.mark.parametrize("schedule,under_jit", [
+    (_page_loads, False), (_mixed, False), (_cross_link_deps, False),
+    (_compute_mid, False), (_tenants, False), (_mixed, True)],
+    ids=["one_page_descriptor", "mixed", "cross_link_deps", "compute_mid",
+         "tenants", "tracers_under_jit"])
+def test_grouped_flush_matches_a_drain_by_steps(schedule, under_jit):
+    """``flush()`` plans the rounds repeated ``step()`` runs and executes
+    them as grouped programs: values bit-identical, and the same
+    completions (task, resource, tenant, round, span), makespan, replay and
+    ``links``/``rings`` counters."""
+    got = _drain(schedule, DistributedScheduler.flush, under_jit)
+    want = _drain(schedule, _steps, under_jit)
+    assert len(got["values"]) == len(want["values"])
+    for g, w in zip(got["values"], want["values"]):
+        np.testing.assert_array_equal(g, w)
+    for key in ("completions", "makespan", "rounds", "links", "rings"):
+        assert got[key] == want[key], key
+    assert got["makespan"][0] == got["makespan"][1]
+    assert got["links"]                             # the drain did count
+
+
+def test_grouped_flush_bounds_programs_and_compiles():
+    """A flush of 200 equal page loads launches 64+64+64+8, four programs;
+    flushes of every size from 1 to 256 compile at most seven round
+    programs (widths 1..64) for that descriptor and geometry."""
+    from repro.core.descriptor import page_descriptor
+    from repro.runtime import scheduler as S
+
+    xdma.clear_cache()
+    desc = page_descriptor(16, 128, "bfloat16", direction="load")
+    x = _page()
+    programs = telemetry.bank("sched")
+
+    def flush(n):
+        sched = DistributedScheduler(Topology.host_device(2))
+        for i in range(n):
+            sched.submit(x, desc, link=("d2h0", "d2h1")[i % 2])
+        before = programs.get("programs")
+        sched.flush()
+        return programs.get("programs") - before
+
+    assert flush(200) == 4
+    for n in range(1, 257):
+        assert flush(n) == len(S._chunk_widths(n)) <= 4 + 6
+    mine = [fn for key, fn in S._ROUND_CACHE.items() if desc in key]
+    assert len(mine) <= 7
+    assert all(fn._cache_size() == 1 for fn in mine)   # one geometry each
+    assert S._chunk_widths(200) == [64, 64, 64, 8]

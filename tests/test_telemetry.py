@@ -470,8 +470,15 @@ def test_engine_phases_on_the_profilers_clock(model, tmp_path):
 
 
 def test_sched_bank_counts_batched_tasks():
-    """``batched_tasks`` counts the XDMA tasks of fused rounds; all XDMA
-    tasks dispatched are the ``links`` bank's ``tasks:<resource>``."""
+    """``batched_tasks`` counts the XDMA tasks that ran inside a fused
+    program, ``programs`` the XDMA programs launched; all XDMA tasks
+    dispatched are the ``links`` bank's ``tasks:<resource>``.
+
+    A flush defers every local concrete task and runs each group of equal
+    descriptors as power-of-two chunks of ``jit_sched_round``: the three
+    equal descriptors of round 0 run as chunks of 2 and 1 (closed when the
+    compute task is picked), and the lone round-1 task as a chunk of 1, so
+    all four tasks ride fused programs, three of them."""
     telemetry.reset("sched")
     links = telemetry.bank("links")
     tasks = lambda: sum(links.with_prefix("tasks:").values())
@@ -484,7 +491,8 @@ def test_sched_bank_counts_batched_tasks():
     sched.submit_compute(lambda: None, cost_s=1e-6)
     sched.flush()
     sched.flush()                                # nothing left: no round
-    assert telemetry.bank("sched").as_dict() == {"batched_tasks": 3}
+    assert telemetry.bank("sched").as_dict() == {"batched_tasks": 4,
+                                                 "programs": 3}
     assert tasks() - before == 4
 
 
