@@ -60,6 +60,10 @@ class ModelConfig:
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     moe_wire_int8: bool = False
+    # (first, count): the contiguous range of the router's ``n_experts``
+    # outputs whose weights live here (one chip's share under expert
+    # parallelism); None holds them all
+    experts_held: Optional[Tuple[int, int]] = None
 
     # SSM (mamba)
     ssm_d_inner: int = 0
@@ -91,6 +95,11 @@ class ModelConfig:
     def n_layers(self) -> int:
         return len(self.period) * self.n_periods + len(self.tail)
 
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.experts_held or (0, self.n_experts)
+
     def with_axes(self, axes: Axes) -> "ModelConfig":
         return dataclasses.replace(self, axes=axes)
 
@@ -98,6 +107,9 @@ class ModelConfig:
         assert self.n_heads % self.n_kv_heads == 0, (self.n_heads, self.n_kv_heads)
         if any(l.moe for l in self.period + self.tail):
             assert self.n_experts > 0 and self.top_k > 0 and self.d_ff_expert > 0
+            first, count = self.held_experts
+            assert 0 <= first and 0 < count and first + count <= self.n_experts, \
+                (self.experts_held, self.n_experts)
         if any(l.kind == MAMBA for l in self.period + self.tail):
             assert self.ssm_d_inner > 0 and self.ssm_heads > 0
 

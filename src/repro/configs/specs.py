@@ -43,7 +43,8 @@ def decode_token_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
 
 
 def count_params(cfg: ModelConfig) -> Tuple[int, int]:
-    """(total, active) parameter counts, from abstract init (no allocation)."""
+    """(total, active) parameter counts, from abstract init (no allocation);
+    with ``experts_held`` set, of the held experts' share."""
     from repro.models import lm
     shapes = jax.eval_shape(lambda k: lm.init_params(k, cfg),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -62,7 +63,7 @@ def count_params(cfg: ModelConfig) -> Tuple[int, int]:
             if any(k in ("w_gate", "w_up", "w_down") for k in path) and \
                "ffn" in path and cfg.n_experts:
                 if tree.shape and tree.shape[-3:-2] != () and len(tree.shape) >= 3 \
-                   and cfg.n_experts in tree.shape:
+                   and cfg.held_experts[1] in tree.shape:
                     expert += math.prod(tree.shape)
     walk(shapes)
     active = total - expert + (expert * cfg.top_k // max(cfg.n_experts, 1))

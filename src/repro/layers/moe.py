@@ -12,12 +12,20 @@ rebuilds the sequence.  This is exactly the paper's "distributed half-XDMA"
 pattern: the descriptor (routing geometry, capacity, plugin chain) is fixed
 at compile time, the link carries only payload.
 
-Local path (tests / no mesh): same math, no collectives.
+Local path (no mesh; the serving engine's path): dropless over the experts
+held here.  The config's ``experts_held`` (first, count) names a contiguous
+range of the router's ``n_experts`` outputs; the layer routes over all of
+them (softmax, top-k, renormalise), keeps the assignments whose expert is
+held, sorts them by expert, and runs the SwiGLU as grouped products over
+the held experts (``lax.ragged_dot``).  No capacity: no token is dropped at
+any batch or routing skew.  Assignments to absent experts add nothing here;
+on the other chips of an expert-parallel deployment they are those chips'
+part of the result.  The ``shard_map`` paths hold every expert and keep
+their capacity buffers.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,15 +39,17 @@ from repro.sharding import constrain, P
 
 
 def init_moe(key, cfg):
+    """Router over all ``n_experts``; weights of the held experts only."""
     E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
+    n = cfg.held_experts[1]
     ks = jax.random.split(key, 4)
     init = jax.nn.initializers.normal(stddev=d ** -0.5)
     down = jax.nn.initializers.normal(stddev=f ** -0.5)
     return {
         "router": init(ks[0], (d, E), jnp.float32),
-        "w_gate": init(ks[1], (E, d, f), jnp.float32),
-        "w_up": init(ks[2], (E, d, f), jnp.float32),
-        "w_down": down(ks[3], (E, f, d), jnp.float32),
+        "w_gate": init(ks[1], (n, d, f), jnp.float32),
+        "w_up": init(ks[2], (n, d, f), jnp.float32),
+        "w_down": down(ks[3], (n, f, d), jnp.float32),
     }
 
 
@@ -56,6 +66,55 @@ def _route(cfg, router_w, tokens):
     p_e = probs.mean(0)
     aux = E * jnp.sum(f_e * p_e)
     return gates, eidx, aux
+
+
+def _held(cfg, eidx):
+    """eidx (T, k) router outputs -> (held (T*k,) bool, group (T*k,) int32:
+    the held expert's local index, ``count`` for an absent expert, and the
+    (count,) sizes of the held groups)."""
+    first, count = cfg.held_experts
+    local = eidx.reshape(-1).astype(jnp.int32) - first
+    held = (local >= 0) & (local < count)
+    group = jnp.where(held, local, count)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+    return held, group, sizes
+
+
+def _counts(n, held, sizes):
+    return jnp.stack([jnp.asarray(n, jnp.int32), held.sum(dtype=jnp.int32),
+                      (sizes > 0).sum(dtype=jnp.int32)])
+
+
+def routing_counts(cfg, eidx):
+    """int32 (3,): assignments (tokens x top_k), those to held experts, and
+    held experts that received at least one token."""
+    held, _, sizes = _held(cfg, eidx)
+    return _counts(eidx.size, held, sizes)
+
+
+def _moe_held(cfg, p, tokens):
+    """Dropless MoE on a (T, d) token slab over the held experts.
+
+    Returns (y (T, d), aux, routing counts)."""
+    T, d = tokens.shape
+    k = cfg.top_k
+    gates, eidx, aux = _route(cfg, p["router"], tokens)
+    held, group, sizes = _held(cfg, eidx)
+    # a token holds at most min(k, count) held assignments: the sorted
+    # prefix of that length covers every held one, the rest are absent
+    M = T * min(k, cfg.held_experts[1])
+    sel = jnp.argsort(group, stable=True)[:M]
+    dt = tokens.dtype
+    xs = XP.GatherScatter(indices=sel // k, axis=0)(tokens)
+    g = lax.ragged_dot(xs, p["w_gate"].astype(dt), sizes)
+    u = lax.ragged_dot(xs, p["w_up"].astype(dt), sizes)
+    out = lax.ragged_dot(jax.nn.silu(g) * u, p["w_down"].astype(dt), sizes,
+                         preferred_element_type=jnp.float32)
+    # rows past the held groups come out of ragged_dot as zeros; their
+    # weight is zero too
+    w = jnp.where(held, gates.reshape(-1), 0.0)[sel][:, None]
+    y = jnp.zeros((T, d), jnp.float32).at[sel // k].add(out * w)
+    return y.astype(dt), aux, _counts(T * k, held, sizes)
 
 
 def _dispatch(cfg, tokens, eidx, gates, capacity):
@@ -159,9 +218,9 @@ def _dispatch_queue(model_axis: str, dtype, wire_plugins) -> XDMAQueue:
     ], name="moe_dispatch")
 
 
-def _moe_tokens(cfg, p, tokens, *, model_axis: Optional[str], n_model: int,
+def _moe_tokens(cfg, p, tokens, *, model_axis: str, n_model: int,
                 wire_plugins=(), scheduler=None, overlap_chunks: int = 2):
-    """Core MoE on a (T, d) token slab; a2a over model_axis when distributed.
+    """Capacity-buffer MoE on a (T, d) token slab, a2a over ``model_axis``.
 
     With a :class:`~repro.runtime.DistributedScheduler` the dispatch buffer is
     split into ``overlap_chunks`` capacity slices, each running its own
@@ -177,9 +236,8 @@ def _moe_tokens(cfg, p, tokens, *, model_axis: Optional[str], n_model: int,
     gates, eidx, aux = _route(cfg, p["router"], tokens)
     capacity = int(cfg.capacity_factor * k * T // E) + 1
 
-    queue = (None if model_axis is None
-             else _dispatch_queue(model_axis, tokens.dtype, wire_plugins))
-    chunked = queue is not None and scheduler is not None and overlap_chunks > 1
+    queue = _dispatch_queue(model_axis, tokens.dtype, wire_plugins)
+    chunked = scheduler is not None and overlap_chunks > 1
     buf, slot, keep, order, tok_of = _dispatch(cfg, tokens, eidx, gates, capacity)
 
     if chunked:
@@ -211,12 +269,10 @@ def _moe_tokens(cfg, p, tokens, *, model_axis: Optional[str], n_model: int,
         out = jnp.concatenate([f.result() for f in futs], axis=1)
         out = out[:, :capacity]          # drop the pad slots before combine
     else:
-        if queue is not None:
-            # (E, C, d) -> (E_local, n_model*C, d): the XDMA dispatch tunnel
-            buf = queue.run_task(buf, 0)
+        # (E, C, d) -> (E_local, n_model*C, d): the XDMA dispatch tunnel
+        buf = queue.run_task(buf, 0)
         out = _expert_ffn(cfg, p, buf)
-        if queue is not None:
-            out = queue.run_task(out, 1)
+        out = queue.run_task(out, 1)
     y = _combine(cfg, out, slot, keep, order, gates, T, d)
     return y, aux
 
@@ -236,15 +292,17 @@ def ep_enabled(cfg, n_model: int) -> bool:
     return cfg.n_experts % n_model == 0
 
 
-def moe_apply(cfg, p, x, *, mesh=None, scheduler=None, overlap_chunks: int = 2):
-    """x (B, S, d) -> (y, aux_loss).
+def moe_apply(cfg, p, x, *, mesh=None, scheduler=None, overlap_chunks: int = 2,
+              with_counts: bool = False):
+    """x (B, S, d) -> (y, aux_loss), and with ``with_counts`` (local path
+    only) the routing counts of :func:`routing_counts` as a third output.
 
     Distributed (cfg.axes.model set + mesh given): runs under shard_map.
       * EP path (E %% n_model == 0, S %% n_model == 0): sequence-split tokens,
         XDMA all_to_all dispatch to the expert shard, mirrored return.
       * TP path (otherwise, incl. decode S=1): tokens replicated over model,
         expert d_ff sharded, one psum (Megatron-style).
-    Local (tests / no mesh): same math, no collectives.
+    Local (no mesh): dropless over the held experts (:func:`_moe_held`).
 
     ``scheduler`` (a :class:`~repro.runtime.DistributedScheduler`) routes the
     EP dispatch through chunked per-link FIFOs so the a2a overlaps expert FFN
@@ -254,8 +312,13 @@ def moe_apply(cfg, p, x, *, mesh=None, scheduler=None, overlap_chunks: int = 2):
     B, S, d = x.shape
     axes = cfg.axes
     if axes.model is None or mesh is None:
-        y, aux = _moe_tokens(cfg, p, x.reshape(-1, d), model_axis=None, n_model=1)
-        return y.reshape(B, S, d), aux
+        y, aux, counts = _moe_held(cfg, p, x.reshape(-1, d))
+        y = y.reshape(B, S, d)
+        return (y, aux, counts) if with_counts else (y, aux)
+    if cfg.held_experts != (0, cfg.n_experts):
+        raise NotImplementedError("the shard_map MoE paths hold every expert")
+    if with_counts:
+        raise NotImplementedError("routing counts come from the local path only")
 
     n_model = mesh.shape[axes.model]
     bspec = axes.batch_spec

@@ -105,7 +105,9 @@ def _constrain_slot_params(cfg, tree):
 
 def _apply_slot(cfg, spec: LayerSpec, p, x, positions, *, cache=None,
                 cache_pos=None, enc_out=None, cross_cache=None, mesh=None,
-                causal=True):
+                causal=True, counts=None):
+    """One slot.  Returns (x, new cache, aux, counts): ``counts`` as passed
+    in, plus a MoE slot's routing counts when it is an array."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
     h = rms_norm(x, p["norm_mix"]["scale"], cfg.norm_eps)
@@ -139,17 +141,27 @@ def _apply_slot(cfg, spec: LayerSpec, p, x, positions, *, cache=None,
         if mc is not None:
             new_cache.update(mc)
         x = x + out
-    if spec.ffn:
-        h = rms_norm(x, p["norm_ffn"]["scale"], cfg.norm_eps)
-        if spec.moe:
-            out, a = MOE.moe_apply(cfg, p["ffn"], h, mesh=mesh)
-            aux = aux + a
-        elif cfg.ffn_kind == "gelu":
-            out = F.gelu_mlp(cfg, p["ffn"], h)
-        else:
-            out = F.swiglu(cfg, p["ffn"], h)
-        x = x + out
-    return x, new_cache, aux
+    x, aux, counts = _ffn_sublayer(cfg, spec, p, x, aux, counts, mesh)
+    return x, new_cache, aux, counts
+
+
+def _ffn_sublayer(cfg, spec: LayerSpec, p, x, aux, counts, mesh):
+    """The slot's FFN (dense or MoE) with its residual.  ``counts`` (None,
+    or an int32 (3,) running total) gains a MoE slot's routing counts."""
+    if not spec.ffn:
+        return x, aux, counts
+    h = rms_norm(x, p["norm_ffn"]["scale"], cfg.norm_eps)
+    if spec.moe and counts is not None:
+        out, a, n = MOE.moe_apply(cfg, p["ffn"], h, mesh=mesh, with_counts=True)
+        aux, counts = aux + a, counts + n
+    elif spec.moe:
+        out, a = MOE.moe_apply(cfg, p["ffn"], h, mesh=mesh)
+        aux = aux + a
+    elif cfg.ffn_kind == "gelu":
+        out = F.gelu_mlp(cfg, p["ffn"], h)
+    else:
+        out = F.swiglu(cfg, p["ffn"], h)
+    return x + out, aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -207,7 +219,7 @@ def _encode(cfg, params, audio_embeds):
 
     def body(x, p):
         p = _constrain_slot_params(enc_cfg, p)
-        y, _, _ = _apply_slot(enc_cfg, spec, p, x, pos, causal=False)
+        y, _, _, _ = _apply_slot(enc_cfg, spec, p, x, pos, causal=False)
         return y, None
 
     if cfg.remat == "block":
@@ -242,8 +254,8 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None):
         x, aux = carry
         slot_params = _constrain_slot_params(cfg, slot_params)
         for spec, p in zip(cfg.period, slot_params):
-            x, _, a = _apply_slot(cfg, spec, p, x, positions,
-                                  enc_out=enc_out, mesh=mesh)
+            x, _, a, _ = _apply_slot(cfg, spec, p, x, positions,
+                                     enc_out=enc_out, mesh=mesh)
             aux = aux + a
         return (x, aux), None
 
@@ -251,8 +263,8 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None):
     (x, aux_total), _ = lax.scan(body, (x, aux_total), params["blocks"])
 
     for spec, p in zip(cfg.tail, params["tail"]):
-        x, _, a = _apply_slot(cfg, spec, p, x, positions, enc_out=enc_out,
-                              mesh=mesh)
+        x, _, a, _ = _apply_slot(cfg, spec, p, x, positions, enc_out=enc_out,
+                                 mesh=mesh)
         aux_total = aux_total + a
 
     x = rms_norm(x, params["norm_final"]["scale"], cfg.norm_eps)
@@ -263,10 +275,19 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None):
 # ---------------------------------------------------------------------------
 # prefill (fills cache) and decode
 # ---------------------------------------------------------------------------
-def prefill(cfg: ModelConfig, params, batch, cache, *, mesh=None):
+def _zero_counts(on: bool):
+    """Routing counts' starting total, or None where none are counted."""
+    return jnp.zeros((3,), jnp.int32) if on else None
+
+
+def prefill(cfg: ModelConfig, params, batch, cache, *, mesh=None,
+            moe_counts: bool = False):
     """Run the prompt through the model, writing KV/state caches.
 
-    Returns (logits_last (B,1,V), cache)."""
+    Returns (logits_last (B,1,V), cache); with ``moe_counts`` also the
+    program's routing counts, int32 (3,): assignments and held assignments
+    summed over layers (:func:`repro.layers.moe.routing_counts`), and 0 in
+    the third place, which counts decode steps' experts only."""
     if "embeds" in batch:
         x = batch["embeds"].astype(cfg.dtype)
         B, S = x.shape[:2]
@@ -293,33 +314,38 @@ def prefill(cfg: ModelConfig, params, batch, cache, *, mesh=None):
                           "len": cache["cross"]["len"]}
 
     aux = jnp.zeros((), jnp.float32)
+    counts = _zero_counts(moe_counts)
 
     def block_body(carry, xs):
-        x, aux = carry
+        x, aux, counts = carry
         slot_params, slot_caches = xs
         slot_params = _constrain_slot_params(cfg, slot_params)
         new_caches = []
         for spec, p, c in zip(cfg.period, slot_params, slot_caches):
-            x, nc, a = _prefill_slot_correct(cfg, spec, p, x, positions, c,
-                                             enc_out=enc_out, mesh=mesh)
+            x, nc, a, counts = _prefill_slot_correct(
+                cfg, spec, p, x, positions, c, enc_out=enc_out, mesh=mesh,
+                counts=counts)
             aux = aux + a
             new_caches.append(nc)
-        return (x, aux), tuple(new_caches)
+        return (x, aux, counts), tuple(new_caches)
 
     body = jax.checkpoint(block_body) if cfg.remat == "block" else block_body
-    (x, aux), new_block_caches = lax.scan(
-        body, (x, aux), (params["blocks"], cache["blocks"]))
+    (x, aux, counts), new_block_caches = lax.scan(
+        body, (x, aux, counts), (params["blocks"], cache["blocks"]))
 
     new_tail = []
     for spec, p, c in zip(cfg.tail, params["tail"], cache["tail"]):
-        x, nc, a = _prefill_slot_correct(cfg, spec, p, x, positions, c,
-                                         enc_out=enc_out, mesh=mesh)
+        x, nc, a, counts = _prefill_slot_correct(
+            cfg, spec, p, x, positions, c, enc_out=enc_out, mesh=mesh,
+            counts=counts)
         new_tail.append(nc)
 
     x = rms_norm(x, params["norm_final"]["scale"], cfg.norm_eps)
     logits = E.lm_head(cfg, params["embed"], x[:, -1:])
     cache = dict(cache, blocks=new_block_caches, tail=tuple(new_tail),
                  pos=jnp.asarray(x.shape[1], jnp.int32))
+    if moe_counts:
+        return logits, cache, counts.at[2].set(0)
     return logits, cache
 
 
@@ -371,8 +397,9 @@ def _write_kv_cache(cfg, spec, attn_p, x_normed, positions, slot_cache):
 
 
 def _prefill_slot_correct(cfg, spec, p, x, positions, slot_cache, *,
-                          enc_out=None, mesh=None):
-    """Apply one slot in prefill mode, producing both output and cache."""
+                          enc_out=None, mesh=None, counts=None):
+    """Apply one slot in prefill mode, producing both output and cache
+    (and ``counts`` as in :func:`_apply_slot`)."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, p["norm_mix"]["scale"], cfg.norm_eps)
     new_cache = dict(slot_cache)
@@ -395,22 +422,16 @@ def _prefill_slot_correct(cfg, spec, p, x, positions, slot_cache, *,
     elif spec.kind == SLSTM:
         out, nc = X.slstm_apply(cfg, p["slstm"], h, cache=slot_cache)
         new_cache, x = nc, x + out
-    if spec.ffn:
-        h = rms_norm(x, p["norm_ffn"]["scale"], cfg.norm_eps)
-        if spec.moe:
-            out, a = MOE.moe_apply(cfg, p["ffn"], h, mesh=mesh)
-            aux = aux + a
-        elif cfg.ffn_kind == "gelu":
-            out = F.gelu_mlp(cfg, p["ffn"], h)
-        else:
-            out = F.swiglu(cfg, p["ffn"], h)
-        x = x + out
-    return x, new_cache, aux
+    x, aux, counts = _ffn_sublayer(cfg, spec, p, x, aux, counts, mesh)
+    return x, new_cache, aux, counts
 
 
-def decode_step(cfg: ModelConfig, params, tokens, cache, *, mesh=None):
+def decode_step(cfg: ModelConfig, params, tokens, cache, *, mesh=None,
+                moe_counts: bool = False):
     """One decode step.  tokens (B,1) (or embeds (B,1,d)); returns
-    (logits (B,1,V), new cache)."""
+    (logits (B,1,V), new cache), and with ``moe_counts`` the step's routing
+    counts, int32 (3,), summed over layers
+    (:func:`repro.layers.moe.routing_counts`)."""
     pos = cache["pos"]
     if tokens.ndim == 3:
         x = tokens.astype(cfg.dtype)
@@ -422,47 +443,38 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, *, mesh=None):
         positions = pos.astype(jnp.int32)[:, None]
     else:
         positions = jnp.full((B, 1), pos, jnp.int32)
+    cross = cache.get("cross")
 
     def block_body(carry, xs):
-        x = carry
-        slot_params, slot_caches, cross = xs
+        x, counts = carry
+        slot_params, slot_caches, cross_xs = xs
         slot_params = _constrain_slot_params(cfg, slot_params)
         new_caches = []
         for spec, p, c in zip(cfg.period, slot_params, slot_caches):
-            x, nc, _ = _apply_slot(cfg, spec, p, x, positions, cache=c,
-                                   cache_pos=pos, cross_cache=cross, mesh=mesh)
+            x, nc, _, counts = _apply_slot(
+                cfg, spec, p, x, positions, cache=c, cache_pos=pos,
+                cross_cache=None if cross is None else cross_xs, mesh=mesh,
+                counts=counts)
             new_caches.append(dict(c, **nc))
-        return x, tuple(new_caches)
+        return (x, counts), tuple(new_caches)
 
-    cross = cache.get("cross")
-    if cross is None:
-        # dummy per-period xs so the scan signature stays uniform
-        cross_xs = jnp.zeros((cfg.n_periods, 0), jnp.int32)
-
-        def block_body(carry, xs):  # noqa: F811 - no-cross variant
-            x = carry
-            slot_params, slot_caches, _ = xs
-            slot_params = _constrain_slot_params(cfg, slot_params)
-            new_caches = []
-            for spec, p, c in zip(cfg.period, slot_params, slot_caches):
-                x, nc, _ = _apply_slot(cfg, spec, p, x, positions, cache=c,
-                                       cache_pos=pos, mesh=mesh)
-                new_caches.append(dict(c, **nc))
-            return x, tuple(new_caches)
-        xs = (params["blocks"], cache["blocks"], cross_xs)
-    else:
-        xs = (params["blocks"], cache["blocks"], cross)
-
-    x, new_block_caches = lax.scan(block_body, x, xs)
+    # without cross-attention, dummy per-period xs keep the scan uniform
+    cross_xs = (jnp.zeros((cfg.n_periods, 0), jnp.int32) if cross is None
+                else cross)
+    counts = _zero_counts(moe_counts)
+    (x, counts), new_block_caches = lax.scan(
+        block_body, (x, counts), (params["blocks"], cache["blocks"], cross_xs))
 
     new_tail = []
     for spec, p, c in zip(cfg.tail, params["tail"], cache["tail"]):
-        x, nc, _ = _apply_slot(cfg, spec, p, x, positions, cache=c,
-                               cache_pos=pos, mesh=mesh)
+        x, nc, _, counts = _apply_slot(cfg, spec, p, x, positions, cache=c,
+                                       cache_pos=pos, mesh=mesh, counts=counts)
         new_tail.append(dict(c, **nc))
 
     x = rms_norm(x, params["norm_final"]["scale"], cfg.norm_eps)
     logits = E.lm_head(cfg, params["embed"], x)
     new_cache = dict(cache, blocks=new_block_caches, tail=tuple(new_tail),
                      pos=pos + 1)
+    if moe_counts:
+        return logits, new_cache, counts
     return logits, new_cache

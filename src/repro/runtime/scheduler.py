@@ -935,6 +935,18 @@ class DistributedScheduler:
             return self.report().makespan
         return self._makespan_inc
 
+    def release(self) -> None:
+        """Drop the input and output buffers of every dispatched task.
+
+        A task's inputs hold the futures of its dependencies and a future
+        holds its scheduler, so a scheduler and its tasks sit in reference
+        cycles until a full collection: a caller that has taken every result
+        it needs frees the device buffers now.  The timeline (``report``,
+        ``makespan``) stays."""
+        for t in self._tasks.values():
+            if t.done:
+                t.inputs, t.value = (), None
+
     def summary(self) -> str:
         lines = [f"DistributedScheduler({self.name!r}, "
                  f"{len(self._tasks)} tasks, {self._rounds} rounds, "

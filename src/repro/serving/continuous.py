@@ -33,6 +33,11 @@ profile beside the device ops (:func:`repro.runtime.telemetry.span`).
 with a session open, its ``ttft_s``/``tbt_s`` histograms take host-clock
 stamps.
 
+A MoE config's programs on the local (no mesh) path also return their
+routing counts; the engine adds them into one device total per ``serve()``
+and reads it once at the end, inside an ``engine.moe_counts`` span, into
+the ``moe`` counter bank (``MOE_COUNTERS``).
+
 ``StaticBatchEngine`` is the baseline: same pool, same kernels, but gang
 admission only (a new batch forms only when the previous one fully drains,
 and finished members keep occupying batch rows and page traffic until the
@@ -62,6 +67,12 @@ HW_FLOPS = 50e12                # matches the MoE capacity-planner's engine
 # Serving SLO counters (DESIGN.md §11): queue-depth high-water, preemption
 # and step tallies — always counting, like every CSR bank.
 _SERVING = _tm.bank("serving")
+
+# The ``moe`` bank's counters, in the order of the programs' routing counts
+# (``repro.layers.moe.routing_counts``): assignments (tokens x top_k) and
+# those to held experts, over prefill and decode; held experts reached, over
+# decode steps and layers.
+MOE_COUNTERS = ("assignments", "assignments_held", "decode_experts_touched")
 
 
 # ---------------------------------------------------------------------------
@@ -160,15 +171,24 @@ def _from_canonical(meta: _LeafMeta, mat: jnp.ndarray,
     return mat.reshape(nb_shape)
 
 
-def _engine_programs(cfg, mesh):
+def _counts_moe(cfg, mesh) -> bool:
+    """Whether the programs return routing counts: a MoE config on the
+    local (no mesh) path."""
+    return mesh is None and any(spec.moe for spec in cfg.period + cfg.tail)
+
+
+def _engine_programs(cfg, mesh, moe: bool):
     """The engine's jitted prefill and decode, built from named functions so
     that a profile names their programs ``jit_engine_prefill`` and
-    ``jit_engine_decode``."""
+    ``jit_engine_decode``.  With ``moe`` they also return their routing
+    counts (``lm.prefill``'s and ``lm.decode_step``'s ``moe_counts``)."""
+
     def engine_prefill(params, batch, cache):
-        return lm.prefill(cfg, params, batch, cache, mesh=mesh)
+        return lm.prefill(cfg, params, batch, cache, mesh=mesh, moe_counts=moe)
 
     def engine_decode(params, tokens, cache):
-        return lm.decode_step(cfg, params, tokens, cache, mesh=mesh)
+        return lm.decode_step(cfg, params, tokens, cache, mesh=mesh,
+                              moe_counts=moe)
 
     return (jax.jit(engine_prefill),
             jax.jit(engine_decode, donate_argnums=(2,)))
@@ -269,7 +289,9 @@ class ContinuousBatchingEngine:
         self.pool = pool if pool is not None else PagedKVPool(
             capacity_pages if capacity_pages is not None else 64, page_rows)
         self.metas, self._template = _leaf_metas(cfg, max_len, cache_dtype)
-        self._prefill, self._decode = _engine_programs(cfg, mesh)
+        self._moe = _counts_moe(cfg, mesh)
+        self._prefill, self._decode = _engine_programs(cfg, mesh, self._moe)
+        self._moe_total = None          # device routing counts of a serve()
         self._n_params = sum(
             int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)
             if getattr(l, "ndim", 0) >= 1)
@@ -304,6 +326,23 @@ class ContinuousBatchingEngine:
 
     def _growth(self, pos: int) -> int:
         return self._footprint(pos + 1) - self._footprint(pos)
+
+    def _run(self, program, *args):
+        """Call the prefill or decode program; a MoE config's routing counts
+        add into the serve's device total, with no host sync."""
+        if not self._moe:
+            return program(*args)
+        logits, cache, counts = program(*args)
+        self._moe_total = self._moe_total + counts
+        return logits, cache
+
+    def _read_moe_counts(self) -> None:
+        """The serve's routing counts, read once into the ``moe`` bank."""
+        with _tm.span("engine.moe_counts", "engine"):
+            n = np.asarray(self._moe_total)
+            bank = _tm.bank("moe")
+            for name, v in zip(MOE_COUNTERS, n):
+                bank.inc(name, int(v))
 
     # -- page scatter/gather -------------------------------------------------
     def _scatter(self, st: _ReqState, cache_b1, *, deps=(), dirty_from=None,
@@ -473,6 +512,8 @@ class ContinuousBatchingEngine:
         self.steps = 0
         self.preemptions = 0
         tel = _tm.active()
+        if self._moe:
+            self._moe_total = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
 
         while (queue or active or preempted) and self.steps < max_steps:
             if not active and not preempted and queue \
@@ -488,6 +529,7 @@ class ContinuousBatchingEngine:
             with _tm.span("engine.step", "engine", step=self.steps,
                           engine=self.name):
                 clock += self._step(clock, queue, active, preempted, tel)
+                self.last_scheduler.release()     # the step's page buffers
                 self.steps += 1
 
                 # stamp every token generated this step at the post-step
@@ -509,6 +551,8 @@ class ContinuousBatchingEngine:
                     else:
                         self._finish(st, active, clock)
 
+        if self._moe:
+            self._read_moe_counts()
         return self._report(states, clock)
 
     def _step(self, clock: float, queue: List[_ReqState],
@@ -542,8 +586,8 @@ class ContinuousBatchingEngine:
                         np.stack([st.req.tokens for st in group]), jnp.int32)
                     cache0 = lm.init_cache(self.cfg, len(group), self.max_len,
                                            self.cache_dtype)
-                    logits, cache = self._prefill(self.params,
-                                                  {"tokens": toks}, cache0)
+                    logits, cache = self._run(self._prefill, self.params,
+                                              {"tokens": toks}, cache0)
                     cost = 2.0 * self._n_params * len(group) * plen / HW_FLOPS
                     cfut = sched.submit_compute(
                         lambda *a: None, cost_s=cost,
@@ -597,7 +641,7 @@ class ContinuousBatchingEngine:
         with _tm.span("engine.decode", "engine", batch=len(active)):
             toks = jnp.asarray([[st.generated[-1]] for st in active],
                                jnp.int32)
-            logits, cache = self._decode(self.params, toks, cache)
+            logits, cache = self._run(self._decode, self.params, toks, cache)
             gfuts = [f for g in gathered for fl in g.values() for f in fl]
             cost = 2.0 * self._n_params * len(active) / HW_FLOPS
             cfut = sched.submit_compute(lambda *a: None, *gfuts, cost_s=cost,

@@ -81,3 +81,27 @@ def test_axes_for_shapes():
     assert ax.seq == "data" and ax.batch == ()
     ax2 = MM.axes_for(mesh, SHAPES["train_4k"])
     assert ax2.batch == ("data",) and ax2.seq is None
+
+
+def test_qwen3_moe_chip_share_parameter_count():
+    """One chip of 16 sharing each layer by expert parallelism: experts 0-7
+    of all 48 layers, the router's 128 outputs, attention and the untied
+    vocabulary whole: 3.35B parameters, 6.71 GB in bf16."""
+    from repro.configs import qwen3_moe_30b_a3b as q
+    from repro.configs.specs import count_params
+    cfg = q.chip_share()
+    assert cfg.held_experts == (0, 8) and cfg.n_experts == 128
+    total, _ = count_params(cfg)
+    d, L, V = 2048, 48, 151936
+    attn = d * 32 * 128 + 2 * d * 4 * 128 + 32 * 128 * d
+    experts = 8 * 3 * d * 768
+    per_layer = attn + experts + d * 128 + 2 * d + 2 * 128
+    assert total == L * per_layer + 2 * V * d + d
+    assert round(total / 1e9, 2) == 3.35
+    assert round(2 * total / 1e9, 2) == 6.71
+    shapes = jax.eval_shape(lambda k: lm.init_params(k, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ffn = shapes["blocks"][0]["ffn"]
+    assert ffn["router"].shape == (L, d, 128)
+    assert ffn["w_gate"].shape == (L, 8, d, 768)
+    assert ffn["w_down"].shape == (L, 8, 768, d)

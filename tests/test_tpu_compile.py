@@ -107,3 +107,23 @@ def test_open_payload_chain_is_refused_not_compiled():
                     P.Compress(block_rows=8))
     ok, reason = plugin_compiler.can_fuse(desc)
     assert not ok and reason == "payload-output:compress_blocksparse"
+
+
+@pytest.mark.parametrize("tokens", [32, 2048])
+def test_held_expert_layer_at_published_widths(tokens, one_chip,
+                                               no_compile_cache):
+    """qwen3-moe-30b-a3b's sparse block at one chip's share (8 of 128
+    experts, d 2048, width 768) for a decode batch and a prefill group: the
+    grouped products over the held experts lower to the TPU's own grouped
+    matmul kernel."""
+    from repro.configs import qwen3_moe_30b_a3b
+    from repro.layers import moe
+    cfg = qwen3_moe_30b_a3b.chip_share()
+    params = jax.eval_shape(lambda: moe.init_moe(jax.random.key(0), cfg))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.bfloat16, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, tokens, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: moe.moe_apply(cfg, p, x)).lower(
+        params, x).compile().as_text()
+    assert "tpu_custom_call" in text, "no grouped matmul kernel"

@@ -81,14 +81,25 @@ def test_moe_combine_conserves_weighted_expert_outputs(seed, top_k_e):
 
 
 def test_moe_dropping_bounded_by_capacity():
-    """With capacity factor ~0, most tokens drop -> output ~ 0 (never NaN)."""
+    """The capacity buffer of the shard_map paths at capacity factor ~0: one
+    slot per expert, so most tokens drop -> output ~ 0 (never NaN)."""
     cfg = dataclasses.replace(configs.smoke_config("mixtral_8x7b"),
                               dtype=jnp.float32, capacity_factor=0.01)
     p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model), jnp.float32)
-    y, aux = MOE.moe_apply(cfg, p, x)
+    tokens = x.reshape(-1, cfg.d_model)
+    T, E = tokens.shape[0], cfg.n_experts
+    gates, eidx, aux = MOE._route(cfg, p["router"], tokens)
+    capacity = int(cfg.capacity_factor * cfg.top_k * T // E) + 1
+    assert capacity == 1
+    buf, slot, keep, order, _ = MOE._dispatch(cfg, tokens, eidx, gates, capacity)
+    y = MOE._combine(cfg, MOE._expert_ffn(cfg, p, buf), slot, keep, order,
+                     gates, T, cfg.d_model)
     assert np.isfinite(np.asarray(y)).all()
     assert np.isfinite(float(aux))
+    # at most E of the T*k assignments keep a slot: the other tokens drop
+    assert int(keep.sum()) <= E
+    assert int((np.abs(np.asarray(y)).max(-1) == 0).sum()) >= T - E
 
 
 def test_int8_wire_roundtrip_precision():
