@@ -12,7 +12,7 @@ position.  Each serving step:
    pool can hold their prompt pages;
 3. **prefill** — admitted prompts run the existing jitted ``lm.prefill``
    (grouped by prompt length), and the valid prefix of every cache leaf
-   scatters into fresh pages;
+   scatters into fresh pages (one ``engine_scatter`` program per group);
 4. **preemption** — if the next decode's page growth exceeds the free pool,
    the youngest requests evict wholesale to host (Compress wire codec)
    until the rest fit;
@@ -20,8 +20,9 @@ position.  Each serving step:
    indirection in reverse), one jitted ``lm.decode_step`` advances every
    active request — a scalar position when the batch is aligned (the exact
    compiled program ``ServingEngine`` runs, which is what makes the parity
-   tests bit-exact) or a per-request position vector when ragged — and the
-   dirty pages scatter back;
+   tests bit-exact) or a per-request position vector when ragged — and one
+   ``engine_scatter`` program cuts the dirty pages from the batched cache
+   for the pool to store;
 6. the simulated clock advances by the step's scheduler makespan.
 
 Each step is one ``engine.step`` span on the host clock, with its phases
@@ -48,6 +49,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -150,19 +152,74 @@ def _leaf_metas(cfg, max_len: int, cache_dtype) -> Tuple[List[_LeafMeta], Any]:
     return metas, t1
 
 
-def _to_canonical(meta: _LeafMeta, leaf_nb: jnp.ndarray) -> jnp.ndarray:
-    """Per-request leaf (batch axis removed) -> the (rows, cols) matrix the
-    pool pages.  Sequence leaves put the token axis outermost so the valid
-    prefix is a row prefix."""
+def _bucket(n: int) -> int:
+    """Pages a scatter program cuts from one leaf: ``n`` rounded up to a
+    power of two (0 stays 0), so a serve compiles a handful of programs."""
+    return 1 << (n - 1).bit_length() if n > 0 else 0
+
+
+def _page_of(meta: _LeafMeta, R: int, leaf: jnp.ndarray, b, j) -> jnp.ndarray:
+    """Page ``j`` of batch row ``b`` of one batched cache leaf: rows
+    ``[j*R, (j+1)*R)`` of the row's canonical (rows, cols) matrix, the rows
+    past its end zero-filled.  A sequence leaf reads only the tokens that
+    cover the page (a window of at most ``ceil(R / rpt) + 1``, moved back
+    where it would run past the sequence's end) and reorders just those into
+    canonical rows.  ``b`` and ``j`` may be traced."""
     if meta.kind == "seq":
-        x = jnp.moveaxis(leaf_nb, meta.seq_axis_nb(), 0)
-        return x.reshape(meta.rows, meta.cols)
-    return leaf_nb.reshape(meta.rows, meta.cols)
+        rpt, S = meta.rpt, leaf.shape[meta.seq_axis]
+        # the page's first row lies at most rpt - gcd(R, rpt) rows into its
+        # first token, so this many tokens always cover it
+        T = min(S, -(-(R + rpt - math.gcd(R, rpt)) // rpt))
+        start = jnp.minimum(j * R // rpt, S - T)
+        starts = [0] * leaf.ndim
+        starts[meta.batch_axis], starts[meta.seq_axis] = b, start
+        sizes = list(leaf.shape)
+        sizes[meta.batch_axis], sizes[meta.seq_axis] = 1, T
+        win = jnp.squeeze(jax.lax.dynamic_slice(leaf, starts, sizes),
+                          meta.batch_axis)
+        mat = jnp.moveaxis(win, meta.seq_axis_nb(), 0).reshape(
+            T * rpt, meta.cols)
+        off = j * R - start * rpt
+    else:
+        row = jnp.squeeze(jax.lax.dynamic_slice_in_dim(
+            leaf, b, 1, axis=meta.batch_axis), meta.batch_axis)
+        mat = row.reshape(meta.rows, meta.cols)
+        off = j * R
+    mat = jnp.pad(mat, ((0, R), (0, 0)))
+    return jax.lax.dynamic_slice_in_dim(mat, off, R)
+
+
+def _scatter_program(metas: Sequence[_LeafMeta], R: int):
+    """The engine's page scatter as one jitted program, named so that a
+    profile calls it ``jit_engine_scatter``.  It takes the paged leaves of a
+    batched cache (``metas`` order), an int32 (n, 2) array of (batch row,
+    page index) entries and the static number of entries of each leaf, and
+    returns each leaf's pages as separate (R, cols) outputs.  A loop cuts
+    one page an iteration, so compiling takes about as long for 512 pages
+    as for one."""
+
+    def engine_scatter(leaves, entries, counts):
+        out, k = [], 0
+        for m, leaf, n in zip(metas, leaves, counts):
+            mine = entries[k:k + n]
+            k += n
+
+            def cut(i, pages, m=m, leaf=leaf, mine=mine):
+                return pages.at[i].set(
+                    _page_of(m, R, leaf, mine[i, 0], mine[i, 1]))
+            pages = jax.lax.fori_loop(
+                0, n, cut, jnp.zeros((n, R, m.cols), leaf.dtype))
+            out.append(tuple(pages[i] for i in range(n)))
+        return tuple(out)
+
+    return jax.jit(engine_scatter, static_argnums=(2,))
 
 
 def _from_canonical(meta: _LeafMeta, mat: jnp.ndarray,
                     nb_shape: Tuple[int, ...]) -> jnp.ndarray:
-    """Inverse of :func:`_to_canonical`."""
+    """A request's canonical (rows, cols) matrix -> its leaf (batch axis
+    removed).  Sequence leaves hold the token axis outermost in the matrix,
+    so the valid prefix is a row prefix."""
     if meta.kind == "seq":
         seq_nb = meta.seq_axis_nb()
         S = nb_shape[seq_nb]
@@ -289,6 +346,9 @@ class ContinuousBatchingEngine:
         self.pool = pool if pool is not None else PagedKVPool(
             capacity_pages if capacity_pages is not None else 64, page_rows)
         self.metas, self._template = _leaf_metas(cfg, max_len, cache_dtype)
+        self._paged = [m for m in self.metas if m.kind in ("seq", "state")]
+        self._scatter_program = _scatter_program(self._paged,
+                                                 self.pool.page_rows)
         self._moe = _counts_moe(cfg, mesh)
         self._prefill, self._decode = _engine_programs(cfg, mesh, self._moe)
         self._moe_total = None          # device routing counts of a serve()
@@ -345,33 +405,66 @@ class ContinuousBatchingEngine:
                 bank.inc(name, int(v))
 
     # -- page scatter/gather -------------------------------------------------
-    def _scatter(self, st: _ReqState, cache_b1, *, deps=(), dirty_from=None,
-                 label: str = "store") -> None:
-        """Write one request's cache (a B=1 slice) into its pages.  With
-        ``dirty_from`` (a token position), sequence leaves only store the
-        pages overlapping rows written at/after that position — one decode
-        step dirties a single page per leaf in the common case."""
-        leaves = jax.tree_util.tree_leaves(cache_b1)
+    def _plan_scatter(self, group: List[_ReqState],
+                      written: Optional[List[int]] = None):
+        """The pages to store for ``group`` (row i of the batched cache is
+        ``group[i]``), allocating new ones: (leaf meta, row, page index,
+        page id) in store order — request, then leaf, then page.  With
+        ``written`` (each row's decoded position), sequence leaves only
+        store the pages overlapping rows written at/after it — one decode
+        step dirties a single page per leaf in the common case; state leaves
+        store whole."""
         R = self.pool.page_rows
         dtype_name = str(jnp.dtype(self.cache_dtype))
-        for m in self.metas:
-            if m.kind in ("pos", "const"):
-                continue
-            leaf_nb = jnp.squeeze(leaves[m.index], axis=m.batch_axis)
-            mat = _to_canonical(m, leaf_nb)
-            plist = st.pages.setdefault(m.index, [])
-            want = self._pages_at(m, st.pos)
-            if m.kind == "seq" and dirty_from is not None:
-                first = (min(dirty_from, self.max_len - 1) * m.rpt) // R
-            else:
+        plan = []
+        for i, st in enumerate(group):
+            for m in self._paged:
+                plist = st.pages.setdefault(m.index, [])
+                want = self._pages_at(m, st.pos)
                 first = 0
-            for j in range(first, want):
-                if j >= len(plist):
-                    plist.append(self.pool.alloc(m.cols, dtype_name))
-                page_mat = jax.lax.dynamic_slice_in_dim(
-                    mat, j * R, R) if (j + 1) * R <= m.rows else jnp.pad(
-                    mat[j * R:], ((0, (j + 1) * R - m.rows), (0, 0)))
-                self.pool.store(plist[j], page_mat, deps=deps, label=label)
+                if m.kind == "seq" and written is not None:
+                    first = (min(written[i], self.max_len - 1) * m.rpt) // R
+                for j in range(first, want):
+                    if j >= len(plist):
+                        plist.append(self.pool.alloc(m.cols, dtype_name))
+                    plan.append((m, i, j, plist[j]))
+        return plan
+
+    def _scatter_pages(self, cache, plan) -> List[jnp.ndarray]:
+        """Cut the planned pages from the batched ``cache`` in one
+        ``engine_scatter`` program; each leaf's page count is bucketed to a
+        power of two and the padded outputs are dropped.  Returns the pages
+        in plan order."""
+        per_leaf = {m.index: [] for m in self._paged}   # (row, page)
+        slot = []                       # (leaf, position in its outputs)
+        for m, i, j, _ in plan:
+            slot.append((m.index, len(per_leaf[m.index])))
+            per_leaf[m.index].append((i, j))
+        counts = tuple(_bucket(len(per_leaf[m.index])) for m in self._paged)
+        entries = np.zeros((sum(counts), 2), np.int32)
+        k = 0
+        for m, n in zip(self._paged, counts):
+            got = np.asarray(per_leaf[m.index], np.int32).reshape(-1, 2)
+            entries[k:k + len(got)] = got
+            k += n
+        leaves = jax.tree_util.tree_leaves(cache)
+        outs = self._scatter_program(
+            tuple(leaves[m.index] for m in self._paged), entries, counts)
+        _SERVING.inc("scatter_programs")
+        by_leaf = {m.index: o for m, o in zip(self._paged, outs)}
+        return [by_leaf[li][k] for li, k in slot]
+
+    def _scatter(self, group: List[_ReqState], cache, *, deps=(),
+                 written: Optional[List[int]] = None,
+                 label: str = "store") -> None:
+        """Store ``group``'s pages from the batched ``cache`` that prefill or
+        decode returned: plan them (:meth:`_plan_scatter`), cut them in one
+        program, then one ``pool.store`` each."""
+        plan = self._plan_scatter(group, written)
+        if not plan:
+            return
+        for (_, _, _, pid), page in zip(plan, self._scatter_pages(cache, plan)):
+            self.pool.store(pid, page, deps=deps, label=label)
 
     def _gather(self, st: _ReqState):
         """Reassemble one request's cache leaves from its pages.  Returns
@@ -431,19 +524,6 @@ class ContinuousBatchingEngine:
                 out[m.index] = jnp.zeros(t_leaves[m.index].shape,
                                          t_leaves[m.index].dtype)
         return jax.tree_util.tree_unflatten(treedef, out)
-
-    def _split_cache(self, cache, n: int):
-        """Batched cache -> per-request B=1 caches (for page scatter)."""
-        leaves, treedef = jax.tree_util.tree_flatten(cache)
-        outs = []
-        for i in range(n):
-            li = list(leaves)
-            for m in self.metas:
-                if m.kind in ("seq", "state"):
-                    li[m.index] = jax.lax.dynamic_slice_in_dim(
-                        leaves[m.index], i, 1, axis=m.batch_axis)
-            outs.append(jax.tree_util.tree_unflatten(treedef, li))
-        return outs
 
     # -- admission policy ----------------------------------------------------
     def _admit(self, active, preempted, queue, clock):
@@ -599,9 +679,7 @@ class ContinuousBatchingEngine:
                         st.generated.append(int(nxt[i]))
                         if tel is not None:
                             self._stamp(st, now, tel)
-                    for st, c1 in zip(group,
-                                      self._split_cache(cache, len(group))):
-                        self._scatter(st, c1, deps=(cfut,), label="store")
+                    self._scatter(group, cache, deps=(cfut,), label="store")
                 sched.flush()
                 self.pool.commit()
 
@@ -649,16 +727,15 @@ class ContinuousBatchingEngine:
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             now = tel.clock() if tel is not None else 0.0
         with _tm.span("engine.scatter", "engine"):
-            for i, (st, c1) in enumerate(
-                    zip(active, self._split_cache(cache, len(active)))):
-                written = st.pos                   # decode wrote this slot
+            written = [st.pos for st in active]    # decode wrote these slots
+            for i, st in enumerate(active):
                 st.pos = min(st.pos + 1, self.max_len)
                 if not st.done_tokens:
                     st.generated.append(int(nxt[i]))
                     if tel is not None:
                         self._stamp(st, now, tel)
-                self._scatter(st, c1, deps=(cfut,), dirty_from=written,
-                              label="decode")
+            self._scatter(active, cache, deps=(cfut,), written=written,
+                          label="decode")
             sched.flush()
             self.pool.commit()
         if self.auto_defrag and self.pool.fragmentation():
