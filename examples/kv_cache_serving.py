@@ -5,8 +5,7 @@ XDMA KV movement.
 
 Flow (paper Fig. 1): a prefill stage computes the KV cache (GeMM cluster,
 tiled layout), XDMA streams it — RMSNorm fused on store, transpose fused on
-load — and a decode stage consumes it.  The same movement is benchmarked in
-``benchmarks/kv_cache.py`` against the iDMA+accelerator baseline.
+load — and a decode stage consumes it.
 """
 import os
 import sys

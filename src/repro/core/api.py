@@ -27,7 +27,7 @@ The CFG phase happens **once per descriptor**: the lowered callable is built
 and (for local movements) jitted on first use, then cached by descriptor
 identity.  Every later ``transfer`` with the same descriptor is a pure Data
 phase — no retracing, no recompilation (see :func:`cache_stats`, which makes
-the property testable, and the ``cfgcache`` benchmark, which measures it).
+the property testable; ``tests/test_api.py``'s CFG-cache tests check it).
 
 :class:`XDMAQueue` is the Controller's in-order task queue (paper §II-B):
 a sequence of descriptors lowered as one fused, ordered program.

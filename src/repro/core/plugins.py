@@ -298,7 +298,7 @@ class CTensor:
     zero-skip is simulated at the cost model, not the buffer); ``mask`` has
     one bool per ``block_rows`` rows and marks blocks that carry any nonzero.
     ``wire_nbytes`` is what the link would actually move: occupied blocks
-    plus the mask side-channel — the number the simulator/benchmarks charge.
+    plus the mask side-channel — the number the simulator charges.
     """
 
     values: jnp.ndarray

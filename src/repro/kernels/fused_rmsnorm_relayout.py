@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import interpret_mode
-from .relayout import _eff_d_buf
+from .agu import eff_d_buf
 
 
 def _kernel(x_ref, w_ref, o_ref, *, tm: int, tn: int, d: int, eps: float,
@@ -37,7 +37,7 @@ def rmsnorm_relayout(x: jnp.ndarray, weight, tile_shape, *, eps: float = 1e-6,
     m, n = x.shape
     tm, tn = tile_shape
     gm, gn = m // tm, n // tn
-    d = _eff_d_buf(gm, d_buf)
+    d = eff_d_buf(gm, d_buf)
     grid = (gm // d,)
     has_weight = weight is not None
     w = weight if has_weight else jnp.zeros((n,), x.dtype)

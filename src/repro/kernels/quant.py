@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import interpret_mode
-from .relayout import _eff_d_buf
+from .agu import eff_d_buf
 
 
 def _kernel(x_ref, v_ref, s_ref, *, tm: int, tn: int, d: int, n: int):
@@ -29,7 +29,7 @@ def quantize_tiled(x: jnp.ndarray, tile_shape=(32, 128), *, d_buf: int = 9):
     m, n = x.shape
     tm, tn = tile_shape
     gm, gn = m // tm, n // tn
-    d = _eff_d_buf(gm, d_buf)
+    d = eff_d_buf(gm, d_buf)
     grid = (gm // d,)
     values, scales = pl.pallas_call(
         functools.partial(_kernel, tm=tm, tn=tn, d=d, n=n),

@@ -22,11 +22,7 @@ import jax.numpy as jnp
 
 from repro.core import layouts as L
 
-from .agu import agu_relayout, eff_d_buf
-
-# Back-compat alias: quant.py and the benchmarks import the burst-depth
-# helper under its historical private name.
-_eff_d_buf = eff_d_buf
+from .agu import agu_relayout
 
 
 # --------------------------------------------------------------------------

@@ -35,6 +35,7 @@ from repro.core import plugins as XP
 from repro.core import api as xdma
 from repro.core.api import XDMAQueue
 from repro.core.descriptor import Endpoint, XDMADescriptor, reduce_descriptor
+from repro.runtime.topology import HW_FLOPS
 from repro.sharding import constrain, P
 
 
@@ -251,7 +252,7 @@ def _moe_tokens(cfg, p, tokens, *, model_axis: str, n_model: int,
         Cc = cap_pad // overlap_chunks
         # simulated FFN cost: 3 (Eloc, n*Cc, d)x(d, f) einsums per chunk at a
         # nominal accelerator rate — enough to place compute on the timeline
-        ffn_s = 6.0 * E * Cc * d * cfg.d_ff_expert / 50e12
+        ffn_s = 6.0 * E * Cc * d * cfg.d_ff_expert / HW_FLOPS
         futs = []
         for c in range(overlap_chunks):
             sub = lax.slice_in_dim(buf, c * Cc, (c + 1) * Cc, axis=1)

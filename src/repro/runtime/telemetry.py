@@ -3,7 +3,7 @@
 Real DMA engines (the modular iDMA of Benz et al., DataMaestro's decoupled
 streamers) expose per-channel CSR performance counters so the numbers a
 paper reports — link utilization, per-transfer control overhead, end-to-end
-latency — are observable in *deployment*, not just in benchmarks.  This
+latency — are observable in *deployment*, not just in a harness.  This
 module is that CSR file for the whole reproduction (DESIGN.md §11):
 
 * :class:`CounterBank` — one bank of named monotonic counters per domain.

@@ -53,6 +53,9 @@ DEFAULT_DOORBELL_COST = 20e-9   # seconds per CSR write
 # the gap between these two constants is the paper's Fig. 4 axis.
 DEFAULT_BURST_OVERHEAD = 50e-9  # seconds per burst, hardware AGU
 SW_ISSUE_OVERHEAD = 1e-6        # seconds per burst, software loop + 1D DMA
+# Nominal accelerator rate that prices simulated compute tasks (the serving
+# engine's prefill/decode, the MoE expert FFN) on the scheduler's timeline.
+HW_FLOPS = 50e12                # FLOP / second
 
 
 @dataclasses.dataclass(frozen=True)

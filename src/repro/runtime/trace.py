@@ -17,9 +17,9 @@ what this module provides (DESIGN.md §9):
   it; with no capture open they pay a single ``is None`` check (zero-cost
   when off).
 * :meth:`TransferTrace.replay` — turn the ledger into
-  :class:`~repro.runtime.simulator.SimTask`\\ s (through the same
-  :func:`~repro.runtime.simulator.queue_sim_tasks` contract path the queue
-  benchmarks use) and simulate the whole application timeline on any
+  :class:`~repro.runtime.simulator.SimTask`\\ s (through
+  :func:`~repro.runtime.simulator.queue_sim_tasks`, the queue's shape/dtype
+  contract path) and simulate the whole application timeline on any
   :class:`~repro.runtime.topology.Topology`, under either cost model:
 
   Both models issue one address per contiguous run of the composed affine

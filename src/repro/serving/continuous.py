@@ -42,8 +42,8 @@ the ``moe`` counter bank (``MOE_COUNTERS``).
 ``StaticBatchEngine`` is the baseline: same pool, same kernels, but gang
 admission only (a new batch forms only when the previous one fully drains,
 and finished members keep occupying batch rows and page traffic until the
-gang completes).  ``benchmarks/serving_load.py`` sweeps both against offered
-load.
+gang completes).  ``tests/test_paged_serving.py`` checks that continuous
+batching outpaces it on the simulated clock under load.
 """
 from __future__ import annotations
 
@@ -58,13 +58,12 @@ import numpy as np
 
 from repro.models import lm
 from repro.runtime import DistributedScheduler, telemetry as _tm
+from repro.runtime.topology import HW_FLOPS
 from repro.serving.paged import (PagedKVPool, default_serving_topology,
                                  pages_for_rows, DEFAULT_PAGE_ROWS)
 from repro.serving.requests import Request
 
 __all__ = ["ContinuousBatchingEngine", "StaticBatchEngine", "ServeReport"]
-
-HW_FLOPS = 50e12                # matches the MoE capacity-planner's engine
 
 # Serving SLO counters (DESIGN.md §11): queue-depth high-water, preemption
 # and step tallies — always counting, like every CSR bank.
