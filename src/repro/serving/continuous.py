@@ -16,11 +16,12 @@ position.  Each serving step:
 4. **preemption** — if the next decode's page growth exceeds the free pool,
    the youngest requests evict wholesale to host (Compress wire codec)
    until the rest fit;
-5. **decode** — active pages gather into a batch cache (page-table
-   indirection in reverse), one jitted ``lm.decode_step`` advances every
-   active request — a scalar position when the batch is aligned (the exact
-   compiled program ``ServingEngine`` runs, which is what makes the parity
-   tests bit-exact) or a per-request position vector when ragged — and one
+5. **decode** — active pages gather and one ``engine_compose`` program
+   lays them out as a batch cache (page-table indirection in reverse), one
+   jitted ``lm.decode_step`` advances every active request — a scalar
+   position when the batch is aligned (the exact compiled program
+   ``ServingEngine`` runs, which is what makes the parity tests bit-exact)
+   or a per-request position vector when ragged — and one
    ``engine_scatter`` program cuts the dirty pages from the batched cache
    for the pool to store;
 6. the simulated clock advances by the step's scheduler makespan.
@@ -151,10 +152,13 @@ def _leaf_metas(cfg, max_len: int, cache_dtype) -> Tuple[List[_LeafMeta], Any]:
     return metas, t1
 
 
-def _bucket(n: int) -> int:
-    """Pages a scatter program cuts from one leaf: ``n`` rounded up to a
-    power of two (0 stays 0), so a serve compiles a handful of programs."""
-    return 1 << (n - 1).bit_length() if n > 0 else 0
+def _bucket(n: int, bits: int = 1) -> int:
+    """Pages a scatter or compose program takes for one leaf: ``n`` rounded
+    up to a number whose binary digits past the first ``bits`` are zero (a
+    power of two by default; 0 stays 0), so a serve compiles a handful of
+    programs."""
+    step = 1 << max(0, n.bit_length() - bits)
+    return -(-n // step) * step
 
 
 def _page_of(meta: _LeafMeta, R: int, leaf: jnp.ndarray, b, j) -> jnp.ndarray:
@@ -225,6 +229,48 @@ def _from_canonical(meta: _LeafMeta, mat: jnp.ndarray,
         rest = tuple(d for j, d in enumerate(nb_shape) if j != seq_nb)
         return jnp.moveaxis(mat.reshape((S,) + rest), 0, seq_nb)
     return mat.reshape(nb_shape)
+
+
+def _compose_program(metas: Sequence[_LeafMeta], template, R: int):
+    """The engine's batch composition as one jitted program, named so that a
+    profile calls it ``jit_engine_compose``.  For each paged leaf (``kind``
+    'seq' or 'state', ``metas`` order) it takes the step's loaded pages as
+    one flat tuple in (request, page) order, padded to a bucketed count, and
+    an int32 (B, 2) array of each batch row's first page in that tuple and
+    its number of pages.  Stacked end to end, the pages hold each request's
+    canonical matrix as one run of rows; a loop writes one batch row an
+    iteration: the run's first P*R rows (P the pages of a full leaf), those
+    past the row's pages zeroed, cut to the leaf's rows and laid out as the
+    row's leaf.  Returns the batched paged leaves and the zero-filled
+    ``const`` leaves."""
+    t_leaves = jax.tree_util.tree_leaves(template)
+    paged = [m for m in metas if m.kind in ("seq", "state")]
+    consts = [t_leaves[m.index] for m in metas if m.kind == "const"]
+
+    def engine_compose(pages, rows):
+        out = []
+        for m, pg, row in zip(paged, pages, rows):
+            t = t_leaves[m.index]
+            nb_shape = t.shape[:m.batch_axis] + t.shape[m.batch_axis + 1:]
+            P = pages_for_rows(m.rows, R)
+            # P zero pages at the end: no row's run reads past the stack
+            flat = jnp.concatenate(pg + (jnp.zeros((P * R, m.cols), t.dtype),))
+            shape = list(t.shape)
+            shape[m.batch_axis] = row.shape[0]
+
+            def put(i, leaf, m=m, nb_shape=nb_shape, P=P, flat=flat, row=row):
+                mat = jax.lax.dynamic_slice_in_dim(flat, row[i, 0] * R, P * R)
+                held = jnp.arange(P * R)[:, None] < row[i, 1] * R
+                mat = jnp.where(held, mat, jnp.zeros_like(mat))[:m.rows]
+                one = jnp.expand_dims(_from_canonical(m, mat, nb_shape),
+                                      m.batch_axis)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    leaf, one, i, m.batch_axis)
+            out.append(jax.lax.fori_loop(0, row.shape[0], put,
+                                         jnp.zeros(shape, t.dtype)))
+        return tuple(out), tuple(jnp.zeros(t.shape, t.dtype) for t in consts)
+
+    return jax.jit(engine_compose)
 
 
 def _counts_moe(cfg, mesh) -> bool:
@@ -347,6 +393,8 @@ class ContinuousBatchingEngine:
         self.metas, self._template = _leaf_metas(cfg, max_len, cache_dtype)
         self._paged = [m for m in self.metas if m.kind in ("seq", "state")]
         self._scatter_program = _scatter_program(self._paged,
+                                                 self.pool.page_rows)
+        self._compose_program = _compose_program(self.metas, self._template,
                                                  self.pool.page_rows)
         self._moe = _counts_moe(cfg, mesh)
         self._prefill, self._decode = _engine_programs(cfg, mesh, self._moe)
@@ -476,52 +524,42 @@ class ContinuousBatchingEngine:
                              for pid in st.pages.get(m.index, [])]
         return futs
 
-    def _compose_leaf(self, m: _LeafMeta, st: _ReqState,
-                      page_vals: List[jnp.ndarray]) -> jnp.ndarray:
-        """Pages -> one per-request cache leaf (batch axis restored), the
-        unvalidated tail zero-filled exactly as ``init_cache`` leaves it."""
-        R = self.pool.page_rows
-        have = len(page_vals) * R
-        if page_vals:
-            mat = jnp.concatenate(page_vals, axis=0)
-            if have < m.rows:
-                mat = jnp.pad(mat, ((0, m.rows - have), (0, 0)))
-            else:
-                mat = mat[:m.rows]
-        else:
-            mat = jnp.zeros((m.rows, m.cols), self.cache_dtype)
-        t_leaf = jax.tree_util.tree_leaves(self._template)[m.index]
-        nb_shape = (t_leaf.shape[:m.batch_axis]
-                    + t_leaf.shape[m.batch_axis + 1:])
-        return jnp.expand_dims(_from_canonical(m, mat, nb_shape),
-                               m.batch_axis)
-
     # -- batch composition ---------------------------------------------------
+    def _compose_args(self, gathered: List[Dict[int, List[Any]]]):
+        """The compose program's arguments: per paged leaf, the loaded pages
+        in (request, page) order, padded to a count with three leading
+        binary digits (at most a quarter padding), and each batch row's
+        (first page, number of pages) in them."""
+        pages, rows = [], []
+        for m in self._paged:
+            flat = [f.result() for g in gathered for f in g[m.index]]
+            counts = [len(g[m.index]) for g in gathered]
+            rows.append(np.stack([np.cumsum([0] + counts[:-1]), counts],
+                                 axis=1).astype(np.int32))
+            # any page pads: the program zeroes rows past each row's pages
+            pad = _bucket(len(flat), bits=3) - len(flat)
+            pages.append(tuple(flat + flat[:1] * pad))
+        return tuple(pages), tuple(rows)
+
     def _compose_cache(self, active: List[_ReqState],
                        gathered: List[Dict[int, List[Any]]]):
-        """Per-request pages -> one batched decode cache.  Scalar ``pos``
-        when the batch is position-aligned (identical compiled program to
-        the fixed-batch engine), per-request vector otherwise."""
+        """Per-request pages -> one batched decode cache, composed in one
+        ``engine_compose`` program.  Scalar ``pos`` when the batch is
+        position-aligned (identical compiled program to the fixed-batch
+        engine), per-request vector otherwise."""
+        paged, consts = self._compose_program(*self._compose_args(gathered))
+        _SERVING.inc("compose_programs")
         t_leaves, treedef = jax.tree_util.tree_flatten(self._template)
         out = list(t_leaves)
+        const_metas = [m for m in self.metas if m.kind == "const"]
+        for m, leaf in zip(self._paged + const_metas, paged + consts):
+            out[m.index] = leaf
         for m in self.metas:
             if m.kind == "pos":
                 poss = [min(st.pos, self.max_len) for st in active]
                 out[m.index] = (jnp.asarray(poss[0], jnp.int32)
                                 if len(set(poss)) == 1
                                 else jnp.asarray(poss, jnp.int32))
-            elif m.kind == "const":
-                out[m.index] = t_leaves[m.index]
-            else:
-                parts = [self._compose_leaf(
-                    m, st, [f.result() for f in gathered[i][m.index]])
-                    for i, st in enumerate(active)]
-                out[m.index] = jnp.concatenate(parts, axis=m.batch_axis)
-        # const template leaves are ShapeDtypeStructs; realize them
-        for m in self.metas:
-            if m.kind == "const":
-                out[m.index] = jnp.zeros(t_leaves[m.index].shape,
-                                         t_leaves[m.index].dtype)
         return jax.tree_util.tree_unflatten(treedef, out)
 
     # -- admission policy ----------------------------------------------------
