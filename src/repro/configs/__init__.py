@@ -18,6 +18,7 @@ ARCHS = (
     "xlstm_125m",
     "qwen2_vl_7b",
     "whisper_small",
+    "kanana_2_30b_a3b",
 )
 
 _ALIASES = {
@@ -31,6 +32,7 @@ _ALIASES = {
     "xlstm-125m": "xlstm_125m",
     "qwen2-vl-7b": "qwen2_vl_7b",
     "whisper-small": "whisper_small",
+    "kanana-2-30b-a3b": "kanana_2_30b_a3b",
 }
 
 

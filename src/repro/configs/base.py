@@ -2,9 +2,11 @@
 
 Every assigned architecture is expressed as a :class:`ModelConfig` whose
 ``period`` (a tuple of :class:`LayerSpec`) describes one repeating block of
-layers; the model scans ``n_periods`` stacked copies plus an optional
-unstacked ``tail`` (e.g. gemma3's 62 = 6*10 + 2).  This keeps HLO size and
-compile time flat in depth (DESIGN.md §5).
+layers; the model scans ``n_periods`` stacked copies between an optional
+unstacked ``lead`` (layers that run first, e.g. DeepSeek-V3-style models'
+leading dense layers) and an optional unstacked ``tail`` (e.g. gemma3's
+62 = 6*10 + 2).  This keeps HLO size and compile time flat in depth
+(DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ ATTN = "attn"       # (optionally windowed) self-attention
 MAMBA = "mamba"
 MLSTM = "mlstm"
 SLSTM = "slstm"
+MLA = "mla"         # multi-head latent attention (DeepSeek-V2/V3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,7 +34,8 @@ class LayerSpec:
     ffn: bool = True                # has an FFN sublayer at all
 
     def cache_kind(self) -> str:
-        return {ATTN: "kv", MAMBA: "ssm", MLSTM: "mlstm", SLSTM: "slstm"}[self.kind]
+        return {ATTN: "kv", MAMBA: "ssm", MLSTM: "mlstm", SLSTM: "slstm",
+                MLA: "latent"}[self.kind]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,12 +51,21 @@ class ModelConfig:
     period: Tuple[LayerSpec, ...]
     n_periods: int
     tail: Tuple[LayerSpec, ...] = ()
+    lead: Tuple[LayerSpec, ...] = ()   # unstacked layers before the periods
 
     # attention details
     rope_theta: float = 10000.0
     qk_norm: bool = False
     qkv_bias: bool = False
     mrope: bool = False
+
+    # latent attention (MLA): queries of qk_nope_head_dim + qk_rope_head_dim
+    # per head (no query compression), one shared kv_lora_rank latent plus a
+    # qk_rope_head_dim rotary key per token, values of v_head_dim per head
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     n_experts: int = 0
@@ -64,6 +77,12 @@ class ModelConfig:
     # outputs whose weights live here (one chip's share under expert
     # parallelism); None holds them all
     experts_held: Optional[Tuple[int, int]] = None
+    # "softmax": top-k of the softmax; "sigmoid": top-k of sigmoid scores
+    # plus a per-expert correction bias, weighted by the unbiased scores
+    # (DeepSeek-V3's noaux_tc with one group)
+    router_score: str = "softmax"
+    routed_scale: float = 1.0       # applied to the renormalised gates
+    d_ff_shared: int = 0            # width of the shared experts' SwiGLU
 
     # SSM (mamba)
     ssm_d_inner: int = 0
@@ -93,7 +112,13 @@ class ModelConfig:
     # -- derived ------------------------------------------------------------
     @property
     def n_layers(self) -> int:
-        return len(self.period) * self.n_periods + len(self.tail)
+        return (len(self.lead) + len(self.period) * self.n_periods
+                + len(self.tail))
+
+    @property
+    def layer_specs(self) -> Tuple[LayerSpec, ...]:
+        """Every distinct slot kind: lead, period and tail."""
+        return self.lead + self.period + self.tail
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -105,13 +130,17 @@ class ModelConfig:
 
     def validate(self) -> None:
         assert self.n_heads % self.n_kv_heads == 0, (self.n_heads, self.n_kv_heads)
-        if any(l.moe for l in self.period + self.tail):
+        if any(l.moe for l in self.layer_specs):
             assert self.n_experts > 0 and self.top_k > 0 and self.d_ff_expert > 0
             first, count = self.held_experts
             assert 0 <= first and 0 < count and first + count <= self.n_experts, \
                 (self.experts_held, self.n_experts)
-        if any(l.kind == MAMBA for l in self.period + self.tail):
+            assert self.router_score in ("softmax", "sigmoid"), self.router_score
+        if any(l.kind == MAMBA for l in self.layer_specs):
             assert self.ssm_d_inner > 0 and self.ssm_heads > 0
+        if any(l.kind == MLA for l in self.layer_specs):
+            assert (self.kv_lora_rank > 0 and self.qk_nope_head_dim > 0
+                    and self.qk_rope_head_dim > 0 and self.v_head_dim > 0)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -410,7 +410,11 @@ def page_layout(rows: int, cols: int, dtype_name: str) -> L.Layout:
     accelerator-native tiled candidate pool: the candidate whose store
     relayout (``MN -> candidate``) is cheapest under the link cost model —
     the dtype-native VREG tiling when it fits, the paper's (8, 8) GeMM-array
-    tile for narrow pages, plain ``MN`` when nothing tile-aligned fits.
+    tile for pages narrower than a 128-wide lane row, plain ``MN`` when
+    nothing tile-aligned fits.  A page a lane row wide or wider whose width
+    is no multiple of 128 (a 576-wide MLA latent page) stays ``MN``: its
+    (8, 8) tiles would each pad to the chip's (16, 128) bfloat16 tile, 32
+    times the page's bytes at rest.
     The restricted candidate pool (not the autotuner's full generated space)
     keeps picks bit-identical to the historical strict-max-burst rule, so
     serving token streams are unchanged; strict ``<`` scoring keeps the
@@ -422,8 +426,9 @@ def page_layout(rows: int, cols: int, dtype_name: str) -> L.Layout:
 
     rows, cols = int(rows), int(cols)
     native = L.layout_for_dtype(jnp.dtype(dtype_name))
+    narrow = (L.MNM8N8,) if cols < 128 else ()
     candidates = (native,) + tuple(l for l in (L.MNM8N128, L.MNM16N128,
-                                               L.MNM32N128, L.MNM8N8)
+                                               L.MNM32N128) + narrow
                                    if l is not native)
     best = _at.best_layout((rows, cols), dtype_name, candidates=candidates)
     return best or L.MN

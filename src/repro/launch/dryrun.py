@@ -172,7 +172,7 @@ def attention_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
     halves it); windowed layers cap S_kv at the window.  Dominates 2*N*D at
     32k+ context, so MFU accounting must include it."""
     B, S = shape.global_batch, shape.seq_len
-    layers = list(cfg.period) * cfg.n_periods + list(cfg.tail)
+    layers = list(cfg.lead) + list(cfg.period) * cfg.n_periods + list(cfg.tail)
     total = 0.0
     for spec in layers:
         if spec.kind != "attn":

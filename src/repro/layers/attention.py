@@ -132,11 +132,12 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
                       q_offset=0, q_chunk=1024, kv_chunk=1024):
     """Flash-style attention with block-sparse pair scheduling.
 
-    q (B,Sq,H,hd); k,v (B,Sk,KV,hd); f32 running max/denominator.  One scan
-    over the *valid* (q-chunk, kv-chunk) pairs; per-chunk state lives in a
-    chunk-indexed carry updated in place (dynamic-update-slice)."""
+    q, k (B,Sq|Sk,H|KV,hd); v (B,Sk,KV,hv), hv may differ from hd (latent
+    attention); f32 running max/denominator.  One scan over the *valid*
+    (q-chunk, kv-chunk) pairs; per-chunk state lives in a chunk-indexed
+    carry updated in place (dynamic-update-slice)."""
     B, Sq, H, hd = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, hv = k.shape[1], k.shape[2], v.shape[3]
     G = H // KV
     qc, kc = _chunk_of(Sq, q_chunk), _chunk_of(Sk, kv_chunk)
     nq, nk = Sq // qc, Sk // kc
@@ -146,14 +147,14 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
     qt = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qt = qt.reshape(B, nq, qc, KV, G, hd).transpose(1, 0, 3, 4, 2, 5)
     kt = k.reshape(B, nk, kc, KV, hd).transpose(1, 0, 3, 2, 4)
-    vt = v.reshape(B, nk, kc, KV, hd).transpose(1, 0, 3, 2, 4)
+    vt = v.reshape(B, nk, kc, KV, hv).transpose(1, 0, 3, 2, 4)
 
     pairs = _chunk_pairs(nq, nk, qc, kc, q_offset, Sk, causal, window)
     pair_arr = jnp.asarray(pairs, jnp.int32)          # (npairs, 2)
 
     m0 = jnp.full((nq, B, KV, G, qc), NEG_INF, jnp.float32)
     l0 = jnp.zeros((nq, B, KV, G, qc), jnp.float32)
-    a0 = jnp.zeros((nq, B, KV, G, qc, hd), jnp.float32)
+    a0 = jnp.zeros((nq, B, KV, G, qc, hv), jnp.float32)
 
     @jax.checkpoint
     def pair_step(carry, pair):
@@ -192,7 +193,7 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
 
     (m, l, acc), _ = lax.scan(pair_step, (m0, l0, a0), pair_arr)
     out = acc / jnp.maximum(l, 1e-30)[..., None]       # (nq,B,KV,G,qc,hd)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, hd)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, hv)
     return out.astype(q.dtype)
 
 

@@ -15,13 +15,17 @@ at compile time, the link carries only payload.
 Local path (no mesh; the serving engine's path): dropless over the experts
 held here.  The config's ``experts_held`` (first, count) names a contiguous
 range of the router's ``n_experts`` outputs; the layer routes over all of
-them (softmax, top-k, renormalise), keeps the assignments whose expert is
-held, sorts them by expert, and runs the SwiGLU as grouped products over
-the held experts (``lax.ragged_dot``).  No capacity: no token is dropped at
-any batch or routing skew.  Assignments to absent experts add nothing here;
-on the other chips of an expert-parallel deployment they are those chips'
-part of the result.  The ``shard_map`` paths hold every expert and keep
-their capacity buffers.
+them (softmax, top-k, renormalise; or, with ``router_score`` "sigmoid",
+top-k of sigmoid scores plus a correction bias, weighted by the unbiased
+scores, renormalised and scaled by ``routed_scale``), keeps the assignments
+whose expert is held, sorts them by expert, and runs the SwiGLU as grouped
+products over the held experts (``lax.ragged_dot``).  No capacity: no token
+is dropped at any batch or routing skew.  Assignments to absent experts add
+nothing here; on the other chips of an expert-parallel deployment they are
+those chips' part of the result.  Shared experts (``d_ff_shared``), which
+every chip holds, add once beside the held ones.  The ``shard_map`` paths
+hold every expert, route by softmax with no shared experts, and keep their
+capacity buffers.
 """
 from __future__ import annotations
 
@@ -35,28 +39,45 @@ from repro.core import plugins as XP
 from repro.core import api as xdma
 from repro.core.api import XDMAQueue
 from repro.core.descriptor import Endpoint, XDMADescriptor, reduce_descriptor
+from repro.layers import mlp as F
 from repro.runtime.topology import HW_FLOPS
 from repro.sharding import constrain, P
 
 
 def init_moe(key, cfg):
-    """Router over all ``n_experts``; weights of the held experts only."""
+    """Router over all ``n_experts`` (with its correction bias when routing
+    by sigmoid); weights of the held experts only, and of the shared ones."""
     E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
     n = cfg.held_experts[1]
     ks = jax.random.split(key, 4)
     init = jax.nn.initializers.normal(stddev=d ** -0.5)
     down = jax.nn.initializers.normal(stddev=f ** -0.5)
-    return {
+    p = {
         "router": init(ks[0], (d, E), jnp.float32),
         "w_gate": init(ks[1], (n, d, f), jnp.float32),
         "w_up": init(ks[2], (n, d, f), jnp.float32),
         "w_down": down(ks[3], (n, f, d), jnp.float32),
     }
+    if cfg.router_score == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
+    if cfg.d_ff_shared:
+        p["shared"] = F.init_swiglu(jax.random.fold_in(key, 4), d,
+                                    cfg.d_ff_shared)
+    return p
 
 
-def _route(cfg, router_w, tokens):
-    """tokens (T, d) -> (gates (T,k), expert ids (T,k), aux load-balance loss)."""
+def _route(cfg, router_w, tokens, bias=None):
+    """tokens (T, d) -> (gates (T,k), expert ids (T,k), aux load-balance loss).
+    ``bias`` is the sigmoid router's per-expert correction."""
     logits = tokens.astype(jnp.float32) @ router_w             # (T, E)
+    if cfg.router_score == "sigmoid":
+        # DeepSeek-V3 noaux_tc, one group: the bias chooses, the unbiased
+        # scores weigh; no auxiliary loss
+        scores = jax.nn.sigmoid(logits)
+        _, eidx = lax.top_k(scores + bias.astype(jnp.float32), cfg.top_k)
+        gates = jnp.take_along_axis(scores, eidx, axis=-1)
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+        return gates * cfg.routed_scale, eidx, jnp.zeros((), jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, eidx = lax.top_k(probs, cfg.top_k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
@@ -99,7 +120,7 @@ def _moe_held(cfg, p, tokens):
     Returns (y (T, d), aux, routing counts)."""
     T, d = tokens.shape
     k = cfg.top_k
-    gates, eidx, aux = _route(cfg, p["router"], tokens)
+    gates, eidx, aux = _route(cfg, p["router"], tokens, p.get("router_bias"))
     held, group, sizes = _held(cfg, eidx)
     # a token holds at most min(k, count) held assignments: the sorted
     # prefix of that length covers every held one, the rest are absent
@@ -115,6 +136,12 @@ def _moe_held(cfg, p, tokens):
     # weight is zero too
     w = jnp.where(held, gates.reshape(-1), 0.0)[sel][:, None]
     y = jnp.zeros((T, d), jnp.float32).at[sel // k].add(out * w)
+    if "shared" in p:
+        s = p["shared"]
+        h = (jax.nn.silu(tokens @ s["w_gate"].astype(dt))
+             * (tokens @ s["w_up"].astype(dt)))
+        y = y + jnp.matmul(h, s["w_down"].astype(dt),
+                           preferred_element_type=jnp.float32)
     return y.astype(dt), aux, _counts(T * k, held, sizes)
 
 
@@ -318,6 +345,9 @@ def moe_apply(cfg, p, x, *, mesh=None, scheduler=None, overlap_chunks: int = 2,
         return (y, aux, counts) if with_counts else (y, aux)
     if cfg.held_experts != (0, cfg.n_experts):
         raise NotImplementedError("the shard_map MoE paths hold every expert")
+    if cfg.router_score != "softmax" or cfg.d_ff_shared:
+        raise NotImplementedError("the shard_map MoE paths route by softmax "
+                                  "with no shared experts")
     if with_counts:
         raise NotImplementedError("routing counts come from the local path only")
 

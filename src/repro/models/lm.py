@@ -1,9 +1,11 @@
 """Scan-stacked decoder LM covering dense / MoE / hybrid / SSM / VLM families.
 
 The depth dimension is ``n_periods`` scanned copies of a heterogeneous
-``period`` (tuple of LayerSpec) plus an optional unstacked ``tail``; params
-and caches for the period are stacked pytrees threaded through ``lax.scan``
-(xs -> ys), so HLO size is O(period), not O(depth).
+``period`` (tuple of LayerSpec) between an optional unstacked ``lead`` and
+an optional unstacked ``tail``; params and caches for the period are stacked
+pytrees threaded through ``lax.scan`` (xs -> ys), so HLO size is O(period),
+not O(depth).  The params' and caches' ``lead`` keys exist only where the
+config has leading layers.
 
 Modes:
   forward(...)                       train / prefill logits (+ MoE aux)
@@ -20,10 +22,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ATTN, MAMBA, MLSTM, SLSTM, LayerSpec, ModelConfig
+from repro.configs.base import (ATTN, MAMBA, MLA, MLSTM, SLSTM, LayerSpec,
+                                ModelConfig)
 from repro.layers import attention as A
 from repro.layers import embedding as E
 from repro.layers import mamba as M
+from repro.layers import mla as LA
 from repro.layers import mlp as F
 from repro.layers import moe as MOE
 from repro.layers import xlstm as X
@@ -42,6 +46,8 @@ def _init_slot(key, cfg: ModelConfig, spec: LayerSpec):
         if cfg.encoder_layers:          # decoder w/ cross-attention (whisper)
             p["norm_cross"] = init_rms(ks[3], cfg.d_model)
             p["cross"] = A.init_attn(jax.random.fold_in(ks[1], 7), cfg, cross=True)
+    elif spec.kind == MLA:
+        p["attn"] = LA.init_mla(ks[1], cfg)
     elif spec.kind == MAMBA:
         p["mamba"] = M.init_mamba(ks[1], cfg)
     elif spec.kind == MLSTM:
@@ -74,6 +80,10 @@ def init_params(key, cfg: ModelConfig):
         _init_slot(jax.random.fold_in(keys[2], ti), cfg, spec)
         for ti, spec in enumerate(cfg.tail))
     params["norm_final"] = init_rms(keys[3], cfg.d_model)
+    if cfg.lead:
+        params["lead"] = tuple(
+            _init_slot(jax.random.fold_in(keys[6], li), cfg, spec)
+            for li, spec in enumerate(cfg.lead))
     if cfg.encoder_layers:
         enc_spec = LayerSpec(ATTN)
         enc_cfg = dataclasses.replace(cfg, encoder_layers=0)  # no cross in encoder
@@ -126,6 +136,14 @@ def _apply_slot(cfg, spec: LayerSpec, p, x, positions, *, cache=None,
                                   cache=cross_cache, apply_rope=False,
                                   cross=True)
             x = x + out
+    elif spec.kind == MLA:
+        if cache is None:
+            out, _ = LA.mla_prefill(cfg, p["attn"], h, positions)
+        else:
+            out, mc = LA.mla_decode(cfg, p["attn"], h, positions, cache,
+                                    cache_pos)
+            new_cache.update(mc)
+        x = x + out
     elif spec.kind == MAMBA:
         out, mc = M.mamba_apply(cfg, p["mamba"], h, cache=cache)
         if mc is not None:
@@ -176,6 +194,8 @@ def _slot_cache(cfg, spec: LayerSpec, B, max_len, dtype):
                     "v": jnp.zeros((B, cfg.n_kv_heads, smax, cfg.head_dim), dtype)}
         kv = (B, smax, cfg.n_kv_heads, cfg.head_dim)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+    if spec.kind == MLA:
+        return LA.init_latent_cache(cfg, B, max_len, dtype)
     if spec.kind == MAMBA:
         return M.init_mamba_cache(cfg, B, dtype)
     if spec.kind == MLSTM:
@@ -198,6 +218,9 @@ def init_cache(cfg: ModelConfig, B: int, max_len: int, dtype=jnp.bfloat16):
         "tail": tuple(_slot_cache(cfg, s, B, max_len, dtype) for s in cfg.tail),
         "pos": jnp.zeros((), jnp.int32),
     }
+    if cfg.lead:
+        cache["lead"] = tuple(_slot_cache(cfg, s, B, max_len, dtype)
+                              for s in cfg.lead)
     if cfg.encoder_layers:
         kv = (B, cfg.encoder_seq, cfg.n_kv_heads, cfg.head_dim)
         cache["cross"] = {
@@ -249,6 +272,10 @@ def forward(cfg: ModelConfig, params, batch, *, mesh=None):
         enc_out = _encode(cfg, params, batch["audio_embeds"])
 
     aux_total = jnp.zeros((), jnp.float32)
+    for spec, p in zip(cfg.lead, params.get("lead", ())):
+        x, _, a, _ = _apply_slot(cfg, spec, p, x, positions, enc_out=enc_out,
+                                 mesh=mesh)
+        aux_total = aux_total + a
 
     def block_body(carry, slot_params):
         x, aux = carry
@@ -315,6 +342,13 @@ def prefill(cfg: ModelConfig, params, batch, cache, *, mesh=None,
 
     aux = jnp.zeros((), jnp.float32)
     counts = _zero_counts(moe_counts)
+    new_lead = []
+    for spec, p, c in zip(cfg.lead, params.get("lead", ()),
+                          cache.get("lead", ())):
+        x, nc, a, counts = _prefill_slot_correct(
+            cfg, spec, p, x, positions, c, enc_out=enc_out, mesh=mesh,
+            counts=counts)
+        new_lead.append(nc)
 
     def block_body(carry, xs):
         x, aux, counts = carry
@@ -344,6 +378,8 @@ def prefill(cfg: ModelConfig, params, batch, cache, *, mesh=None,
     logits = E.lm_head(cfg, params["embed"], x[:, -1:])
     cache = dict(cache, blocks=new_block_caches, tail=tuple(new_tail),
                  pos=jnp.asarray(x.shape[1], jnp.int32))
+    if cfg.lead:
+        cache["lead"] = tuple(new_lead)
     if moe_counts:
         return logits, cache, counts.at[2].set(0)
     return logits, cache
@@ -413,6 +449,9 @@ def _prefill_slot_correct(cfg, spec, p, x, positions, slot_cache, *,
             out, _ = A.attn_apply(cfg, p["cross"], hc, positions, causal=False,
                                   kv_x=enc_out, apply_rope=False)
             x = x + out
+    elif spec.kind == MLA:
+        out, lat = LA.mla_prefill(cfg, p["attn"], h, positions)
+        new_cache, x = LA.write_latent(slot_cache, lat), x + out
     elif spec.kind == MAMBA:
         out, nc = M.mamba_apply(cfg, p["mamba"], h, cache=slot_cache)
         new_cache, x = nc, x + out
@@ -444,6 +483,13 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, *, mesh=None,
     else:
         positions = jnp.full((B, 1), pos, jnp.int32)
     cross = cache.get("cross")
+    counts = _zero_counts(moe_counts)
+    new_lead = []
+    for spec, p, c in zip(cfg.lead, params.get("lead", ()),
+                          cache.get("lead", ())):
+        x, nc, _, counts = _apply_slot(cfg, spec, p, x, positions, cache=c,
+                                       cache_pos=pos, mesh=mesh, counts=counts)
+        new_lead.append(dict(c, **nc))
 
     def block_body(carry, xs):
         x, counts = carry
@@ -461,7 +507,6 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, *, mesh=None,
     # without cross-attention, dummy per-period xs keep the scan uniform
     cross_xs = (jnp.zeros((cfg.n_periods, 0), jnp.int32) if cross is None
                 else cross)
-    counts = _zero_counts(moe_counts)
     (x, counts), new_block_caches = lax.scan(
         block_body, (x, counts), (params["blocks"], cache["blocks"], cross_xs))
 
@@ -475,6 +520,8 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, *, mesh=None,
     logits = E.lm_head(cfg, params["embed"], x)
     new_cache = dict(cache, blocks=new_block_caches, tail=tuple(new_tail),
                      pos=pos + 1)
+    if cfg.lead:
+        new_cache["lead"] = tuple(new_lead)
     if moe_counts:
         return logits, new_cache, counts
     return logits, new_cache

@@ -276,7 +276,7 @@ def _compose_program(metas: Sequence[_LeafMeta], template, R: int):
 def _counts_moe(cfg, mesh) -> bool:
     """Whether the programs return routing counts: a MoE config on the
     local (no mesh) path."""
-    return mesh is None and any(spec.moe for spec in cfg.period + cfg.tail)
+    return mesh is None and any(spec.moe for spec in cfg.layer_specs)
 
 
 def _engine_programs(cfg, mesh, moe: bool):

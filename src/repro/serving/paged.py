@@ -9,7 +9,7 @@ Nothing touches page storage except `_submit`, so a
 :func:`repro.runtime.trace.capture` around a serving run sees *every* page
 byte (the zero-out-of-plane contract ``tests/test_paged_serving.py``
 asserts: ``pool.stats["movements"]`` equals the count of ``page:``-labelled
-trace events).
+trace events, and ``bytes_moved`` sums the logical bytes of those pages).
 
 At rest a page lives in the layout :func:`~repro.core.descriptor.page_layout`
 picks for its geometry (the Iris automatic-layout idea, per page); host-
@@ -87,6 +87,10 @@ class Page:
     dtype: str
     location: str = "dev"           # "dev" | "host"
     data: Any = None
+    nbytes: int = dataclasses.field(init=False)   # of the logical matrix
+
+    def __post_init__(self):
+        self.nbytes = self.rows * self.cols * jnp.dtype(self.dtype).itemsize
 
 
 class PagedKVPool:
@@ -124,7 +128,7 @@ class PagedKVPool:
         _tm.register(self._bank)
 
     _STAT_KEYS = ("stores", "loads", "evictions", "restores",
-                  "defrag_moves", "movements", "peak_used")
+                  "defrag_moves", "movements", "bytes_moved", "peak_used")
 
     @property
     def stats(self) -> Dict[str, int]:
@@ -159,10 +163,11 @@ class PagedKVPool:
         self._lane += 1
         return names[si] if kind == "out" else names[(si + 1) % len(names)]
 
-    def _submit(self, data, desc, *, kind: str, label: str, deps=()):
+    def _submit(self, page: Page, data, desc, *, kind: str, label: str,
+                deps=()):
         """The pool's single movement primitive — every page byte goes
         through here, so the movement counter and the capture ledger agree
-        exactly.
+        exactly; ``bytes_moved`` adds the page's logical bytes.
 
         Honors ring backpressure: on an ``error``-policy scheduler whose
         ring is out of credits, drain one scheduling round (a completion
@@ -179,6 +184,7 @@ class PagedKVPool:
             except WouldBlock:
                 sched.step()
         self._bank.inc("movements")
+        self._bank.inc("bytes_moved", page.nbytes)
         return fut
 
     # -- queries -------------------------------------------------------------
@@ -225,7 +231,7 @@ class PagedKVPool:
         if p.location != "dev":
             raise ValueError(f"page {pid} is host-resident; restore it first")
         desc = page_descriptor(p.rows, p.cols, p.dtype, direction="store")
-        fut = self._submit(mat, desc, kind="out", deps=deps,
+        fut = self._submit(p, mat, desc, kind="out", deps=deps,
                            label=f"page:{pid}:{label}")
         self._pending.append((p, fut, "dev", p.slot))
         self._bank.inc("stores")
@@ -239,7 +245,7 @@ class PagedKVPool:
             raise ValueError(f"page {pid} is host-resident; restore it first")
         desc = page_descriptor(p.rows, p.cols, p.dtype, direction="load")
         self._bank.inc("loads")
-        return self._submit(p.data, desc, kind="in", deps=deps,
+        return self._submit(p, p.data, desc, kind="in", deps=deps,
                             label=f"page:{pid}:load")
 
     def evict(self, pid: int, *, deps=()):
@@ -250,7 +256,7 @@ class PagedKVPool:
             raise ValueError(f"page {pid} already host-resident")
         desc = page_descriptor(p.rows, p.cols, p.dtype, direction="load",
                                wire_compress_rows=self.compress_block)
-        fut = self._submit(p.data, desc, kind="in", deps=deps,
+        fut = self._submit(p, p.data, desc, kind="in", deps=deps,
                            label=f"page:{pid}:evict")
         self._pending.append((p, fut, "host", -1))
         self._bank.inc("evictions")
@@ -267,7 +273,7 @@ class PagedKVPool:
         slot = self._free_slots.pop(0)
         desc = page_descriptor(p.rows, p.cols, p.dtype, direction="store",
                                wire_compress_rows=self.compress_block)
-        fut = self._submit(p.data, desc, kind="out", deps=deps,
+        fut = self._submit(p, p.data, desc, kind="out", deps=deps,
                            label=f"page:{pid}:restore")
         self._pending.append((p, fut, "dev", slot))
         self._bank.inc("restores")
@@ -298,7 +304,7 @@ class PagedKVPool:
             self._free_slots.pop(0)
             desc = page_descriptor(hi.rows, hi.cols, hi.dtype,
                                    direction="copy")
-            fut = self._submit(hi.data, desc, kind="out",
+            fut = self._submit(hi, hi.data, desc, kind="out",
                                label=f"page:{hi.pid}:defrag")
             self._pending.append((hi, fut, "dev", lo))
             self._free_slots.append(hi.slot)

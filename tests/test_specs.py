@@ -105,3 +105,32 @@ def test_qwen3_moe_chip_share_parameter_count():
     assert ffn["router"].shape == (L, d, 128)
     assert ffn["w_gate"].shape == (L, 8, d, 768)
     assert ffn["w_down"].shape == (L, 8, 768, d)
+
+
+def test_kanana_chip_share_parameter_count():
+    """One chip of 16 sharing each layer by expert parallelism: experts 0-7
+    of the 47 sparse layers, the router's 128 outputs and correction bias,
+    all latent attention, both shared experts, the dense layer 0 and the
+    untied vocabulary whole: 4,057,956,224 parameters, 8.12 GB in bf16."""
+    from repro.configs import kanana_2_30b_a3b as k
+    from repro.configs.specs import count_params
+    cfg = k.chip_share()
+    assert cfg.held_experts == (0, 8) and cfg.n_experts == 128
+    total, _ = count_params(cfg)
+    d, V, H, r = 2048, 128256, 32, 512
+    attn = (d * H * 192 + d * (r + 64) + r * H * (128 + 128) + H * 128 * d
+            + r + 2 * d)                        # projections, three norms
+    dense = 3 * d * 6144
+    sparse = d * 128 + 128 + 8 * 3 * d * 768 + 3 * d * 1536
+    assert total == 48 * attn + dense + 47 * sparse + 2 * V * d + d
+    assert total == 4_057_956_224
+    assert round(2 * total / 1e9, 2) == 8.12
+    shapes = jax.eval_shape(lambda k_: lm.init_params(k_, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ffn = shapes["blocks"][0]["ffn"]
+    assert ffn["router"].shape == (47, d, 128)
+    assert ffn["w_gate"].shape == (47, 8, d, 768)
+    assert ffn["shared"]["w_down"].shape == (47, 1536, d)
+    assert shapes["lead"][0]["ffn"]["w_gate"].shape == (d, 6144)
+    assert shapes["lead"][0]["attn"]["wkv_b"].shape == (r, H * 256)
+    assert count_params(k.config())[0] == 30_670_815_104
